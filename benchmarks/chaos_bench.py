@@ -25,8 +25,10 @@ plane -> live ``AdaptiveController`` monitor loop
 ``BENCH_chaos.json`` records both lanes: ``single_device`` (transient
 device loss — the only recoverable shape without a survivor) and
 ``forced_8_device`` (permanent loss -> quarantine + re-place onto
-survivors, run in a subprocess under
-``XLA_FLAGS=--xla_force_host_platform_device_count=8``).
+survivors, on the CPU backend under
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``).  Every lane
+runs in a child process of its own, one after the other, so the
+parent never imports JAX and never holds the accelerator a child needs.
 
 ``--smoke`` is the CI tier1-chaos entry: tiny trace, fixed seed and
 schedule, both lanes, schema-gated, writes nothing.
@@ -116,7 +118,9 @@ def run_chaos(n_patients: int = 6, windows_per_patient: int = 10,
     from repro.serving.pipeline import EnsembleService, ZooMember
     from repro.serving.server import EnsembleServer
 
-    n_devices = min(n_devices, jax.device_count())
+    if n_devices > jax.device_count():
+        raise ValueError(f"lane needs {n_devices} devices, JAX has "
+                         f"{jax.device_count()}")
     rng = np.random.default_rng(seed)
     specs = zoo_specs(reduced=True, input_len=input_len)
     pool = [ZooMember(s, init_ecg(jax.random.PRNGKey(i), s))
@@ -479,7 +483,9 @@ def run_slot_chaos(n_beds: int = 5, n_steps: int = 240,
     from repro.serving.server import EnsembleServer
     from repro.serving.slots import SlotEngine, TickLadder
 
-    n_devices = min(n_devices, jax.device_count())
+    if n_devices > jax.device_count():
+        raise ValueError(f"lane needs {n_devices} devices, JAX has "
+                         f"{jax.device_count()}")
     rng = np.random.default_rng(seed)
     specs = zoo_specs(reduced=True, input_len=input_len)
     pool = [ZooMember(s, init_ecg(jax.random.PRNGKey(i), s))
@@ -874,17 +880,22 @@ def check_chaos_file(path: str = BENCH_JSON) -> None:
 
 
 # ----------------------------------------------------- lane dispatch
-def _subprocess_lane(n_patients: int, windows: int,
-                     seed: int = 0, lane: str = "flush") -> Dict:
-    """Run a forced-8-device lane in a subprocess (XLA device count is
-    fixed at jax init, so the multi-device lanes need their own
-    process).  ``lane`` picks the flush soak or the slot-engine soak;
-    for the slot lane ``n_patients``/``windows`` mean initial beds /
-    driver steps."""
+def _child_lane(n_patients: int, windows: int, seed: int = 0,
+                lane: str = "flush", devices: int = 1) -> Dict:
+    """Run one lane in a child process and return its result dict.
+    Every lane runs in a child, one after the other, so the parent
+    never imports JAX: a parent holding the accelerator would leave
+    the child without one.  ``devices > 1`` is a forced-HOST-device
+    lane (the XLA device count is fixed at JAX start-up): the child
+    runs on the CPU backend with that many host devices.  ``lane``
+    picks the flush soak or the slot-engine soak; for the slot lane
+    ``n_patients``/``windows`` mean initial beds / driver steps."""
     import tempfile
     env = dict(os.environ)
-    env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count"
-                        f"={N_FORCED}")
+    if devices > 1:
+        env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count"
+                            f"={devices}")
+        env["JAX_PLATFORMS"] = "cpu"
     env.pop("PYTEST_CURRENT_TEST", None)
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env["PYTHONPATH"] = os.path.join(repo, "src")
@@ -893,14 +904,14 @@ def _subprocess_lane(n_patients: int, windows: int,
     try:
         r = subprocess.run(
             [sys.executable, os.path.abspath(__file__), "--emit",
-             out_path, "--lane", lane, "--devices", str(N_FORCED),
+             out_path, "--lane", lane, "--devices", str(devices),
              "--n-patients", str(n_patients),
              "--windows", str(windows), "--seed", str(seed)],
             cwd=repo, env=env, capture_output=True, text=True,
             timeout=1200)
         if r.returncode != 0:
-            raise RuntimeError("forced-8-device lane failed:\n"
-                               + (r.stdout or "")[-2000:]
+            raise RuntimeError(f"{lane} lane on {devices} device(s) "
+                               f"failed:\n" + (r.stdout or "")[-2000:]
                                + (r.stderr or "")[-4000:])
         with open(out_path) as f:
             return json.load(f)
@@ -941,6 +952,8 @@ if __name__ == "__main__":
     SLOT_FULL_STEPS = 900
 
     if args.emit:
+        from repro.compile_cache import enable_compile_cache
+        enable_compile_cache()
         if args.lane == "slots":
             out = run_slot_chaos(n_beds=args.n_patients or 5,
                                  n_steps=args.windows or SLOT_FULL_STEPS,
@@ -953,52 +966,40 @@ if __name__ == "__main__":
             check_chaos_schema(out)
         with open(args.emit, "w") as f:
             json.dump(out, f, indent=2)
-    elif args.smoke:
-        lane1 = run_chaos(n_patients=args.n_patients or 4,
-                          windows_per_patient=args.windows or 8,
-                          n_devices=1, seed=args.seed)
-        check_chaos_schema(lane1)
-        lane8 = _subprocess_lane(args.n_patients or 4,
-                                 args.windows or 8, seed=args.seed)
-        check_chaos_schema(lane8)
-        assert lane8["n_devices"] >= 2 and lane8["quarantined"]
-        slane1 = run_slot_chaos(n_steps=SLOT_SMOKE_STEPS,
-                                n_devices=1, seed=args.seed)
-        check_slot_lane_schema(slane1)
-        slane8 = _subprocess_lane(5, SLOT_SMOKE_STEPS, seed=args.seed,
-                                  lane="slots")
-        check_slot_lane_schema(slane8)
-        assert slane8["n_devices"] >= 2 and slane8["quarantined"] \
-            and slane8["rebinds"] >= 1
-        print("chaos smoke OK (flush + slot lanes, single-device + "
-              "forced-8-device)")
     else:
-        lane1 = run_chaos(n_patients=args.n_patients or 6,
-                          windows_per_patient=args.windows or 10,
-                          n_devices=1, seed=args.seed)
+        n_pat = args.n_patients or (4 if args.smoke else 6)
+        n_win = args.windows or (8 if args.smoke else 10)
+        slot_steps = SLOT_SMOKE_STEPS if args.smoke else SLOT_FULL_STEPS
+        lane1 = _child_lane(n_pat, n_win, seed=args.seed)
         check_chaos_schema(lane1)
-        lane8 = _subprocess_lane(args.n_patients or 6,
-                                 args.windows or 10, seed=args.seed)
+        lane8 = _child_lane(n_pat, n_win, seed=args.seed,
+                            devices=N_FORCED)
         check_chaos_schema(lane8)
-        slane1 = run_slot_chaos(n_steps=SLOT_FULL_STEPS, n_devices=1,
-                                seed=args.seed)
+        slane1 = _child_lane(5, slot_steps, seed=args.seed, lane="slots")
         check_slot_lane_schema(slane1)
-        slane8 = _subprocess_lane(5, SLOT_FULL_STEPS, seed=args.seed,
-                                  lane="slots")
+        slane8 = _child_lane(5, slot_steps, seed=args.seed, lane="slots",
+                             devices=N_FORCED)
         check_slot_lane_schema(slane8)
-        # the committed, replayable fault traces the soaks survived
-        # (FaultPlane.to_json / from_json round-trips these)
-        from repro.control.faults import (FaultPlane,
-                                          slot_compound_schedule)
-        tdir = os.path.join(os.path.dirname(__file__), "traces")
-        os.makedirs(tdir, exist_ok=True)
-        for nd, fname in ((1, "slot_compound_1dev.json"),
-                          (N_FORCED, "slot_compound_8dev.json")):
-            FaultPlane(slot_compound_schedule(nd, seed=args.seed),
-                       seed=args.seed).to_json(
-                os.path.join(tdir, fname))
-        _merge_bench_json({"single_device": lane1,
-                           "forced_8_device": lane8,
-                           "slots": {"single_device": slane1,
-                                     "forced_8_device": slane8}})
-        check_chaos_file()
+        if args.smoke:
+            assert lane8["n_devices"] >= 2 and lane8["quarantined"]
+            assert slane8["n_devices"] >= 2 and slane8["quarantined"] \
+                and slane8["rebinds"] >= 1
+            print("chaos smoke OK (flush + slot lanes, single-device + "
+                  "forced-8-device)")
+        else:
+            # the committed, replayable fault traces the soaks survived
+            # (FaultPlane.to_json / from_json round-trips these)
+            from repro.control.faults import (FaultPlane,
+                                              slot_compound_schedule)
+            tdir = os.path.join(os.path.dirname(__file__), "traces")
+            os.makedirs(tdir, exist_ok=True)
+            for nd, fname in ((1, "slot_compound_1dev.json"),
+                              (N_FORCED, "slot_compound_8dev.json")):
+                FaultPlane(slot_compound_schedule(nd, seed=args.seed),
+                           seed=args.seed).to_json(
+                    os.path.join(tdir, fname))
+            _merge_bench_json({"single_device": lane1,
+                               "forced_8_device": lane8,
+                               "slots": {"single_device": slane1,
+                                         "forced_8_device": slane8}})
+            check_chaos_file()

@@ -345,6 +345,8 @@ if __name__ == "__main__":
                          "gate, writes nothing")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         bench_obs(n_patients=8, windows_per_patient=2, reps=2,
                   seed=args.seed, overhead_budget_pct=50.0,

@@ -19,6 +19,8 @@ def main(argv=None) -> None:
                     help="comma-separated benchmark names")
     args = ap.parse_args(argv)
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import composition, serving_bench
     from benchmarks.roofline_table import bench_roofline
     from benchmarks.zoo_setup import build_zoo

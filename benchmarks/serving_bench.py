@@ -5,10 +5,11 @@ fused-serving before/after microbench (``bench_fused_serving``), and
 the multi-device placement sweep (``bench_placement_sweep``) — both
 tracked in ``BENCH_serving.json``.
 
-The placement sweep needs forced host devices; run it standalone as
-``python benchmarks/serving_bench.py`` (which sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before jax
-initialises) or under the CI multi-device lane's env.
+On the CPU the placement sweep needs forced host devices; run it
+standalone as ``JAX_PLATFORMS=cpu python benchmarks/serving_bench.py``
+(which then sets ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
+before jax initialises) or under the CI multi-device lane's env.  On an
+accelerator it sweeps the chips JAX finds.
 """
 from __future__ import annotations
 
@@ -694,11 +695,15 @@ if __name__ == "__main__":
                          "BENCH_serving.json['ingest'] schema, write "
                          "nothing")
     args = ap.parse_args()
-    # force host devices before jax initialises (jax is imported
-    # lazily): the placement benches need a multi-device pool in BOTH
-    # modes; the unsharded benches are indifferent to the count
-    os.environ.setdefault(
-        "XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+    # on the CPU backend, force host devices before jax initialises
+    # (jax is imported lazily): the placement benches need a
+    # multi-device pool in BOTH modes.  On an accelerator they use the
+    # chips JAX finds.
+    if os.environ.get("JAX_PLATFORMS") == "cpu":
+        os.environ.setdefault(
+            "XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         bench_fused_serving(n_patients=4, reps=2, input_len=250,
                             write_json=False)

@@ -84,8 +84,11 @@ def build_zoo(reduced: bool = True, n_patients: int = 32,
                    val_labels=(val["label"] == 1).astype(int))
 
     # measured per-member serving cost (closed-loop, jitted — the paper's
-    # mu measurement), cached alongside the zoo
-    costs_path = os.path.join(CACHE, f"costs_{tag}.json")
+    # mu measurement), cached alongside the zoo under the device kind it
+    # was measured on: another device's costs are not this one's
+    import jax
+    kind = "_".join(jax.devices()[0].device_kind.split())
+    costs_path = os.path.join(CACHE, f"costs_{tag}_{kind}.json")
     if os.path.exists(costs_path):
         with open(costs_path) as f:
             measured = json.load(f)

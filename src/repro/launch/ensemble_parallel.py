@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 
 
 def stack_members(member_params: list):
@@ -89,9 +89,7 @@ def dryrun_ensemble(n_members: int = 4, multi_pod: bool = True,
     with mesh:
         compiled = jax.jit(step).lower(stacked, batch).compile()
     coll = collective_bytes(compiled.as_text())
-    ca = compiled.cost_analysis()            # list-of-dicts on older jax
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
+    ca = compiled.cost_analysis()
     rec = {"mesh": "2x16x16" if multi_pod else "16x16",
            "n_members": n_members,
            "collective_bytes": coll,

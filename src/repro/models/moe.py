@@ -63,7 +63,7 @@ def moe_apply_sharded(p, x: jax.Array, cfg: ArchConfig, mesh, *,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
 
     bt = tuple(a for a in mesh.axis_names if a in ("pod", "data"))
     bt_spec = bt if len(bt) > 1 else bt[0]

@@ -78,13 +78,18 @@ from repro.serving.aggregator import (DeviceIngest, DeviceWindowRef,
 
 log = logging.getLogger(__name__)
 
-# the CPU backend cannot donate buffers (jax copies instead, which is
-# semantically identical); the once-per-compile warning would otherwise
-# fire on every engine's first tick
-warnings.filterwarnings(
-    "ignore", message="Some donated buffers were not usable")
-
 _DEVICE_LOST_CLS = None
+
+
+def _quiet_cpu_donation() -> None:
+    """The CPU backend cannot donate buffers (jax copies instead, which
+    is semantically identical), so its once-per-compile warning would
+    fire on every engine's first tick.  On an accelerator the state
+    buffer is donatable, and a failed donation is a fault worth seeing:
+    the warning stays on there."""
+    if jax.default_backend() == "cpu":
+        warnings.filterwarnings(
+            "ignore", message="Some donated buffers were not usable")
 
 
 def _device_lost(e: BaseException) -> bool:
@@ -182,6 +187,7 @@ class SlotEngine:
             raise ValueError("SlotEngine needs at least one zoo member")
         if "ecg" not in ingest.states:
             raise ValueError("SlotEngine needs an 'ecg' ingest ring")
+        _quiet_cpu_donation()
         self.service = service
         self.ingest = ingest
         self.n_slots = ingest.n_patients

@@ -499,6 +499,7 @@ def test_multi_device_chaos_subprocess():
     env = dict(os.environ)
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count"
                         f"={N_FORCED}")
+    env["JAX_PLATFORMS"] = "cpu"          # forced HOST devices
     env.pop("PYTEST_CURRENT_TEST", None)
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     r = subprocess.run(

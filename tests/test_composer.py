@@ -1,10 +1,7 @@
 """Ensemble composer (Algorithm 1/2) unit + property tests."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:
-    from _hypothesis_shim import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.baselines import (accuracy_first, latency_first, npo,
                                   random_baseline)

@@ -4,10 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:
-    from _hypothesis_shim import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs.registry import get_config
 from repro.kernels import ref

@@ -6,7 +6,7 @@ Three layers:
 * pure LPT invariants (always run): member conservation, load/cost
   accounting, imbalance >= 1, makespan <= serial cost, monotone
   non-increasing makespan in device count, stability under duplicate
-  costs — property-based via hypothesis (or the seeded shim);
+  costs — property-based via hypothesis;
 * ``multi_device``-marked wall-clock tests (need 8 forced host
   devices): sharded ``predict``/``predict_batch`` bitwise-equal to the
   single-device path for every ladder selector at 1/2/4/8 devices,
@@ -27,10 +27,7 @@ import time
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:
-    from _hypothesis_shim import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 import jax
 
@@ -748,6 +745,7 @@ def test_multi_device_lane_subprocess():
     env = dict(os.environ)
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count"
                         f"={N_FORCED}")
+    env["JAX_PLATFORMS"] = "cpu"          # forced HOST devices
     env.pop("PYTEST_CURRENT_TEST", None)
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     r = subprocess.run(

@@ -3,10 +3,7 @@ placement — including the property that the network-calculus T_q bound
 dominates empirical queueing delay."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:
-    from _hypothesis_shim import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.profiles import ModelProfile, ModelZoo, SystemConfig
 from repro.serving.aggregator import (AggState, ModalitySpec,

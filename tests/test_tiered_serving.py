@@ -3,7 +3,7 @@ priority-aware shedding.
 
 Four layers:
 
-* DES conservation properties (hypothesis-or-shim): under census churn
+* DES conservation properties (hypothesis): under census churn
   AND mid-stay acuity escalation, every query is served exactly once,
   by exactly its birth-tier's ensemble (never dropped, double-served,
   or answered by the wrong tier's selector), per-tier counts sum to the
@@ -31,10 +31,7 @@ from collections import Counter
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:
-    from _hypothesis_shim import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.control.controller import (Decision, TieredController,
                                       TieredControllerConfig)
