@@ -3,13 +3,13 @@ span tracing, and the Prometheus/JSONL export surface."""
 from repro.obs.sketch import (EDGES, N_BINS, REL_ERR_BOUND,
                               WindowedSketch, quantile_from_counts)
 from repro.obs.spans import (SERVICE_STAGES, STAGES, SpanRecord,
-                             SpanRecorder, collect, note)
+                             SpanRecorder, collect, note, phase)
 from repro.obs.export import MetricsExporter, start_metrics_server
 
 __all__ = [
     "EDGES", "N_BINS", "REL_ERR_BOUND", "WindowedSketch",
     "quantile_from_counts",
     "SERVICE_STAGES", "STAGES", "SpanRecord", "SpanRecorder",
-    "collect", "note",
+    "collect", "note", "phase",
     "MetricsExporter", "start_metrics_server",
 ]

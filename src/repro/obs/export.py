@@ -280,5 +280,10 @@ def start_metrics_server(exporter: MetricsExporter, port: int = 0,
 
 
 def write_spans_jsonl(tracer, path: str) -> int:
-    """JSONL trace export (one span per line); returns the span count."""
-    return tracer.export_jsonl(path)
+    """JSONL trace export of the recorder's retained spans (one span
+    per line); returns the span count."""
+    spans = tracer.spans()
+    with open(path, "w") as f:
+        for s in spans:
+            f.write(json.dumps(s.to_json()) + "\n")
+    return len(spans)

@@ -23,6 +23,19 @@ this thread, so the pipeline's hot path pays one attribute load and a
 truthiness check when tracing is off — the bench asserts the whole
 plane stays within its overhead budget.
 
+``phase(name)`` puts a stretch of host work on both clocks at once: it
+opens a ``jax.profiler.TraceAnnotation`` (so a profiler trace shows the
+span beside the device's programs) and ``note()``s its duration into
+the active sink.  The slot engine's tick phases (``slots.tick.*``), the
+ticker's cycle (``slots.tick``, ``slots.ticker.sleep``) and the ingest
+call (``ingest.<modality>``) are such spans.  PERF.md §3 gives the
+measured cost of a call, with the profiler off and on.
+
+A slot-mode ``SpanRecord`` also carries ``t_tick0``/``t_tick1``: when
+the tick that first stamped the close's score took its snapshot and
+when it stamped, so a close's latency splits into submit -> covering
+tick starts -> tick stamps -> retire.
+
 Failure paths are first-class: a NaN retirement carries
 ``status="failed"`` and a watchdog kill ``status="watchdog"``, so the
 trace stream tells apart "slow but fine" from "died on device".
@@ -30,15 +43,13 @@ trace stream tells apart "slow but fine" from "died on device".
 from __future__ import annotations
 
 import dataclasses
-import json
 import threading
+import time
 from collections import deque
 from contextlib import contextmanager
 from typing import Dict, Iterator, List, Optional
 
-import numpy as np
-
-from repro.obs import sketch as _sk
+from jax.profiler import TraceAnnotation
 
 # service-stage keys the pipeline reports via note(); queue/coalesce
 # come from the server's own stamps
@@ -72,6 +83,28 @@ def collect() -> Iterator[Dict[str, float]]:
         _tls.acc = None
 
 
+class phase:
+    """A named host span: a profiler ``TraceAnnotation`` for the block,
+    and its duration ``note()``d under ``name`` into this thread's
+    active ``collect()`` sink (dropped when none is active).  A plain
+    class, not a generator context manager: it runs on every ingest
+    call and about nine times a tick."""
+
+    __slots__ = ("name", "_ann", "_t0")
+
+    def __init__(self, name: str):
+        self.name = name
+        self._ann = TraceAnnotation(name)
+
+    def __enter__(self) -> None:
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc) -> None:
+        note(self.name, time.perf_counter() - self._t0)
+        self._ann.__exit__(*exc)
+
+
 @dataclasses.dataclass(frozen=True)
 class SpanRecord:
     """One retired query's lifecycle, stamps in ``time.monotonic``
@@ -87,6 +120,10 @@ class SpanRecord:
     marshal_s: float
     dispatch_s: float
     gather_s: float
+    # slot mode: snapshot and stamp instants of the tick that first
+    # stamped the served score (None on the flush path)
+    t_tick0: Optional[float] = None
+    t_tick1: Optional[float] = None
 
     @property
     def queue_s(self) -> float:
@@ -115,6 +152,8 @@ class SpanRecord:
              "t_retire": self.t_retire, "batch_n": self.batch_n,
              "e2e_s": self.e2e_s, "service_s": self.service_s}
         d.update(self.stage_seconds())
+        if self.t_tick0 is not None:
+            d.update(t_tick0=self.t_tick0, t_tick1=self.t_tick1)
         return d
 
 
@@ -139,7 +178,6 @@ class SpanRecorder:
         self.n_by_status: Dict[str, int] = {}
         self._stage_sum: Dict[str, float] = {s: 0.0 for s in STAGES}
         self._e2e_sum = 0.0
-        self._e2e_hist = np.zeros(_sk.N_BINS)
 
     # ------------------------------------------------------------ write
     def record(self, span: SpanRecord) -> None:
@@ -151,7 +189,6 @@ class SpanRecorder:
             for stage, sec in span.stage_seconds().items():
                 self._stage_sum[stage] += sec
             self._e2e_sum += span.e2e_s
-            self._e2e_hist[_sk.bin_index(span.e2e_s)] += 1.0
 
     # ------------------------------------------------------------- read
     def spans(self) -> List[SpanRecord]:
@@ -182,16 +219,3 @@ class SpanRecorder:
             "mean_e2e_s": e2e / n if n else 0.0,
             "coverage": measured / e2e if e2e > 0 else 0.0,
         }
-
-    def e2e_quantile(self, pct: float) -> float:
-        with self._lock:
-            return _sk.quantile_from_counts(self._e2e_hist, pct)
-
-    # ------------------------------------------------------------ export
-    def export_jsonl(self, path: str) -> int:
-        """Dump the retained spans as JSON-lines; returns the count."""
-        spans = self.spans()
-        with open(path, "w") as f:
-            for s in spans:
-                f.write(json.dumps(s.to_json()) + "\n")
-        return len(spans)

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ref as kref
+from repro.obs import spans as _spans
 
 
 # ------------------------------------------------- actor implementation
@@ -322,12 +323,13 @@ class DeviceIngest:
 
     def ingest(self, t: float, patient: int, modality: str,
                samples: np.ndarray) -> None:
-        samples = np.atleast_2d(np.asarray(samples, np.float32))
-        self.states[modality] = ingest_chunk(
-            self.states[modality], patient, samples)
-        self.fed[modality][patient] += samples.shape[-1]
-        if self.window_start[patient] is None:
-            self.window_start[patient] = t
+        with _spans.phase(f"ingest.{modality}"):
+            samples = np.atleast_2d(np.asarray(samples, np.float32))
+            self.states[modality] = ingest_chunk(
+                self.states[modality], patient, samples)
+            self.fed[modality][patient] += samples.shape[-1]
+            if self.window_start[patient] is None:
+                self.window_start[patient] = t
 
     def window_ready(self, patient: int, now: float) -> bool:
         ws = self.window_start[patient]

@@ -84,10 +84,13 @@ class Task:
     fields except the first three are stamped lazily on the trace
     path; ``__slots__`` keeps the per-query footprint tuple-sized.
     ``version`` is the slot engine's close version under
-    ``engine="slots"`` (which tick must land before the read)."""
+    ``engine="slots"`` (which tick must land before the read), and
+    ``t_tick0``/``t_tick1`` the snapshot and stamp instants of the tick
+    whose score the read returned."""
 
     __slots__ = ("patient", "windows", "t_window", "tier",
-                 "t_dequeue", "t_flush", "batch_n", "stages", "version")
+                 "t_dequeue", "t_flush", "batch_n", "stages", "version",
+                 "t_tick0", "t_tick1")
 
     def __init__(self, patient: int, windows: Dict, t_window: float,
                  tier: object = None):
@@ -100,6 +103,8 @@ class Task:
         self.batch_n = 1
         self.stages: Optional[Dict[str, float]] = None
         self.version = 0
+        self.t_tick0: Optional[float] = None
+        self.t_tick1: Optional[float] = None
 
 
 class ServerStats:
@@ -409,7 +414,8 @@ class EnsembleServer:
                     batch_n=task.batch_n,
                     marshal_s=st.get("marshal", 0.0),
                     dispatch_s=st.get("dispatch", 0.0),
-                    gather_s=st.get("gather", 0.0)))
+                    gather_s=st.get("gather", 0.0),
+                    t_tick0=task.t_tick0, t_tick1=task.t_tick1))
             self._results.put((task.patient, score, lat, task.windows))
         for _ in tasks:
             self.q.task_done()
@@ -580,7 +586,8 @@ class EnsembleServer:
             task.t_flush = time.monotonic()
             if ok:
                 try:
-                    score = eng.read(task.patient)
+                    score, task.t_tick0, task.t_tick1 = \
+                        eng.read_stamped(task.patient)
                 except KeyError:          # discharged after scoring
                     score = float("nan")
             else:

@@ -73,6 +73,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import spans as _spans
 from repro.serving.aggregator import (DeviceIngest, DeviceWindowRef,
                                       gather_windows, pow2_rung)
 
@@ -145,7 +146,13 @@ class TickReport:
     actually ADVANCED this tick (the ABA/version guards can drop a
     computed score), aligned index-for-index — together with ``spad``
     (the pad rung the tick dispatched at) they are exactly what an
-    offline oracle needs to re-score the tick bitwise."""
+    offline oracle needs to re-score the tick bitwise.
+
+    ``phases`` holds the host seconds of each ``slots.tick.*`` phase
+    (snapshot, gather, dispatch, fold, readback, combine, stamp), run
+    one after another.  On a tick that scored a slot ``seconds`` ends
+    where the stamp begins, so every phase but the stamp lies inside
+    it."""
     tick: int                       # tick ordinal after this tick
     n_scored: int                   # occupied slots scored this tick
     n_stale: int                    # occupied slots skipped (ring overrun)
@@ -156,6 +163,7 @@ class TickReport:
     scores: Optional[np.ndarray] = None    # combined score per stamped slot
     spad: int = 0                   # pad rung (oracle batch size)
     skipped: bool = False           # tick-lock timeout: nothing ran
+    phases: Dict[str, float] = dataclasses.field(default_factory=dict)
 
 
 class SlotEngine:
@@ -174,7 +182,8 @@ class SlotEngine:
     * ``read(slot)`` — the slot's latest combined score, host int
       indexing only (NaN before the first scoring or past the tick-age
       guard); ``wait_scored(slot, version)`` blocks until the tick
-      covering that close version lands.
+      covering that close version lands; ``read_stamped(slot)`` adds
+      when that tick took its snapshot and stamped.
     """
 
     def __init__(self, service, ingest: DeviceIngest):
@@ -234,13 +243,15 @@ class SlotEngine:
         self.last_scored_tick = np.full(self.n_slots, -1, np.int64)
         self._admit_epoch = np.zeros(self.n_slots, np.int64)
         self.mirror = np.full(self.n_slots, np.nan)   # float64 oracle
+        # time.monotonic instants at which the tick that first stamped
+        # each slot's current score version took its snapshot / stamped
+        self._tick_t0 = np.full(self.n_slots, np.nan)
+        self._tick_t1 = np.full(self.n_slots, np.nan)
         self.tick_count = 0
         # counters (bench surface)
         self.dispatch_count = 0      # stacked bucket dispatches by ticks
         self.n_admits = 0
         self.n_discharges = 0
-        self.n_stale_total = 0
-        self.tick_seconds = 0.0
         self.n_tick_faults = 0       # DeviceLostError raised inside a tick
         self.n_tick_aborts = 0       # ticks abandoned (fault, no recovery)
         self.n_tick_skips = 0        # ticks skipped on the tick lock
@@ -368,6 +379,10 @@ class SlotEngine:
                 self._admit_epoch = np.pad(self._admit_epoch, (0, add))
                 self.mirror = np.pad(self.mirror, (0, add),
                                      constant_values=np.nan)
+                self._tick_t0 = np.pad(self._tick_t0, (0, add),
+                                       constant_values=np.nan)
+                self._tick_t1 = np.pad(self._tick_t1, (0, add),
+                                       constant_values=np.nan)
                 self.n_slots = new_n
                 self._Spad = new_spad
                 self.n_grows += 1
@@ -495,7 +510,9 @@ class SlotEngine:
             attempts = 0
             while True:
                 try:
-                    report = self._tick_attempt()
+                    with _spans.collect() as phases:
+                        report = self._tick_attempt()
+                    report.phases = dict(phases)
                     break
                 except Exception as e:
                     if not _device_lost(e):
@@ -517,7 +534,8 @@ class SlotEngine:
         cb = self.on_tick
         if cb is not None:
             try:
-                cb(report)
+                with _spans.phase("slots.tick.on_tick"):
+                    cb(report)
             except Exception:
                 log.exception("on_tick callback failed")
         return report
@@ -525,100 +543,109 @@ class SlotEngine:
     def _tick_attempt(self) -> TickReport:
         t0 = time.perf_counter()
         svc = self.service
-        with self._lock:
-            spad = self._Spad
-            pj = self._pj
-            occ = self.occupied & self.has_window
-            ends = {m: a.copy() for m, a in self._ends.items()}
-            valid = {m: a.copy() for m, a in self._valid.items()}
-            versions = self._close_version.copy()
-            epochs = self._admit_epoch.copy()
-            extras = list(self._extra)
-        stale = self._stale_mask(occ, ends, valid)
-        mask = occ & ~stale
-        scored = np.flatnonzero(mask)
-        empty = np.zeros(0, np.int64)
-        if not len(scored):
+        with _spans.phase("slots.tick.snapshot"):
             with self._lock:
+                t_snap = time.monotonic()
+                spad = self._Spad
+                pj = self._pj
+                occ = self.occupied & self.has_window
+                ends = {m: a.copy() for m, a in self._ends.items()}
+                valid = {m: a.copy() for m, a in self._valid.items()}
+                versions = self._close_version.copy()
+                epochs = self._admit_epoch.copy()
+                extras = list(self._extra)
+            stale = self._stale_mask(occ, ends, valid)
+            mask = occ & ~stale
+            scored = np.flatnonzero(mask)
+        if not len(scored):
+            with _spans.phase("slots.tick.stamp"), self._lock:
                 self.tick_count += 1
-                self.n_stale_total += int(stale.sum())
-                self.tick_seconds += time.perf_counter() - t0
+                tick = self.tick_count
+                wall = time.perf_counter() - t0
                 self._cv.notify_all()
-                return TickReport(self.tick_count, 0, int(stale.sum()),
-                                  time.perf_counter() - t0, scored,
-                                  stamped=empty, versions=empty,
-                                  scores=np.zeros(0), spad=spad)
+            empty = np.zeros(0, np.int64)
+            return TickReport(tick, 0, int(stale.sum()), wall, scored,
+                              stamped=empty, versions=empty,
+                              scores=np.zeros(0), spad=spad)
 
         # ---- phase 1: gather + dispatch.  No persistent state is
         # touched and every guard fires HERE, so a DeviceLostError
         # anywhere in this phase aborts with all group states intact.
         guard = svc.dispatch_guard
-        if guard is not None:
-            guard(None)      # the ingest rings live on the default device
+        with _spans.phase("slots.tick.gather"):
+            if guard is not None:
+                guard(None)  # the ingest rings live on the default device
 
-        # one fused gather per distinct window length, over ALL slots
-        # (masked-out columns carry garbage and are dropped on device)
-        st = self.ingest.states["ecg"]
-        cap = st.buf.shape[-1]
-        pad = spad - self.n_slots
-        ej = jnp.asarray(np.pad((ends["ecg"] % cap).astype(np.int32),
-                                (0, pad)))
-        vj = jnp.asarray(np.pad(
-            np.where(mask, valid["ecg"], 0).astype(np.int32), (0, pad)))
-        packs = {L: gather_windows(st.buf, pj, ej, vj, L)
-                 for L in self._lens}
-        dev_wins, _ = svc._ship_packs(packs)    # D2D for remote shards
-
-        vit_rows = None
-        if svc.vitals_model is not None \
-                and "vitals" in self.ingest.states:
-            vst = self.ingest.states["vitals"]
-            vcap = vst.buf.shape[-1]
-            vej = jnp.asarray(np.pad(
-                (ends["vitals"] % vcap).astype(np.int32), (0, pad)))
-            vvj = jnp.asarray(np.pad(
-                np.where(mask, valid["vitals"], 0).astype(np.int32),
+            # one fused gather per distinct window length, over ALL
+            # slots (masked-out columns carry garbage and are dropped
+            # on device)
+            st = self.ingest.states["ecg"]
+            cap = st.buf.shape[-1]
+            pad = spad - self.n_slots
+            ej = jnp.asarray(np.pad((ends["ecg"] % cap).astype(np.int32),
+                                    (0, pad)))
+            vj = jnp.asarray(np.pad(
+                np.where(mask, valid["ecg"], 0).astype(np.int32),
                 (0, pad)))
-            vit_rows = np.asarray(gather_windows(
-                vst.buf, pj, vej, vvj, self.ingest.want["vitals"]))
+            packs = {L: gather_windows(st.buf, pj, ej, vj, L)
+                     for L in self._lens}
+            dev_wins, _ = svc._ship_packs(packs)  # D2D for remote shards
 
-        occ_dev = self._occ_device(mask)
-        group_cands: List[Tuple[jax.Array, ...]] = []
-        n_disp = 0
-        for g in self.groups:
-            cands = []
-            for b in g.buckets:
-                if guard is not None:
-                    guard(b.device)
-                cands.append(b.fn(
-                    b.stacked, dev_wins[(b.spec.input_len, b.device)]))
-            n_disp += len(g.buckets)
-            group_cands.append(tuple(cands))
+            vit_rows = None
+            if svc.vitals_model is not None \
+                    and "vitals" in self.ingest.states:
+                vst = self.ingest.states["vitals"]
+                vcap = vst.buf.shape[-1]
+                vej = jnp.asarray(np.pad(
+                    (ends["vitals"] % vcap).astype(np.int32), (0, pad)))
+                vvj = jnp.asarray(np.pad(
+                    np.where(mask, valid["vitals"], 0).astype(np.int32),
+                    (0, pad)))
+                vit_rows = np.asarray(gather_windows(
+                    vst.buf, pj, vej, vvj, self.ingest.want["vitals"]))
+            occ_dev = self._occ_device(mask)
+
+        with _spans.phase("slots.tick.dispatch"):
+            group_cands: List[Tuple[jax.Array, ...]] = []
+            n_disp = 0
+            for g in self.groups:
+                cands = []
+                for b in g.buckets:
+                    if guard is not None:
+                        guard(b.device)
+                    cands.append(b.fn(
+                        b.stacked, dev_wins[(b.spec.input_len, b.device)]))
+                n_disp += len(g.buckets)
+                group_cands.append(tuple(cands))
 
         # ---- phase 2: fold.  Every guard has passed; the donated
         # updates commit each group's state for this tick.
-        combined = None
-        for g, cands in zip(self.groups, group_cands):
-            g.state, combined = _masked_update(
-                g.state, cands, occ_dev[g.device])
-        if len(self.groups) == 1:
-            self.device_scores = combined
-        else:
-            anchor = self.groups[0].device
-            self.device_scores = _fleet_mean(tuple(
-                jax.device_put(g.state, anchor) for g in self.groups))
+        with _spans.phase("slots.tick.fold"):
+            combined = None
+            for g, cands in zip(self.groups, group_cands):
+                g.state, combined = _masked_update(
+                    g.state, cands, occ_dev[g.device])
+            if len(self.groups) == 1:
+                self.device_scores = combined
+            else:
+                anchor = self.groups[0].device
+                self.device_scores = _fleet_mean(tuple(
+                    jax.device_put(g.state, anchor) for g in self.groups))
 
         # host mirror: exact _combine numerics (float64 mean over the
         # member column + CPU-side vitals/labs models) from one small
         # per-tick readback — this sync point plays the flush's gather
-        score_mat = np.zeros((len(svc.members), spad))
-        for g in self.groups:
-            score_mat[g.rows] = np.asarray(jax.block_until_ready(g.state))
-        fresh: Dict[int, float] = {}
-        for s in scored:
-            fresh[int(s)] = self._host_combine(
-                score_mat[:, s], extras[s],
-                vit_rows[s] if vit_rows is not None else None)
+        with _spans.phase("slots.tick.readback"):
+            score_mat = np.zeros((len(svc.members), spad))
+            for g in self.groups:
+                score_mat[g.rows] = np.asarray(
+                    jax.block_until_ready(g.state))
+        with _spans.phase("slots.tick.combine"):
+            fresh: Dict[int, float] = {}
+            for s in scored:
+                fresh[int(s)] = self._host_combine(
+                    score_mat[:, s], extras[s],
+                    vit_rows[s] if vit_rows is not None else None)
 
         hook = self._pre_stamp_hook
         if hook is not None:
@@ -626,8 +653,10 @@ class SlotEngine:
 
         wall = time.perf_counter() - t0
         stamped: List[int] = []
-        with self._lock:
+        with _spans.phase("slots.tick.stamp"), self._lock:
+            t_stamp = time.monotonic()
             self.tick_count += 1
+            tick = self.tick_count
             for s, sc in fresh.items():
                 # a slot discharged (or churned to a new occupant, or
                 # closed a NEWER window — whose samples the gather may
@@ -637,21 +666,21 @@ class SlotEngine:
                         or self._admit_epoch[s] != epochs[s] \
                         or self._close_version[s] != versions[s]:
                     continue
+                if self.scored_version[s] < versions[s]:
+                    # first tick to stamp this close: its instants
+                    self._tick_t0[s] = t_snap
+                    self._tick_t1[s] = t_stamp
                 self.mirror[s] = sc
                 self.scored_version[s] = versions[s]
-                self.last_scored_tick[s] = self.tick_count
+                self.last_scored_tick[s] = tick
                 stamped.append(s)
             self.dispatch_count += n_disp
-            self.n_stale_total += int(stale.sum())
-            self.tick_seconds += wall
             self._cv.notify_all()
-            st_ids = np.asarray(stamped, np.int64)
-            return TickReport(
-                self.tick_count, len(scored), int(stale.sum()), wall,
-                scored, stamped=st_ids,
-                versions=versions[st_ids].copy(),
-                scores=np.asarray([fresh[int(s)] for s in st_ids]),
-                spad=spad)
+        st_ids = np.asarray(stamped, np.int64)
+        return TickReport(
+            tick, len(scored), int(stale.sum()), wall,
+            scored, stamped=st_ids, versions=versions[st_ids].copy(),
+            scores=np.asarray([fresh[int(s)] for s in st_ids]), spad=spad)
 
     def _host_combine(self, score_col: np.ndarray, extra: Dict,
                       vit_row: Optional[np.ndarray]) -> float:
@@ -682,15 +711,32 @@ class SlotEngine:
         ticker stops a slot's score version from advancing, and this
         guard keeps such a slot from serving an old score forever)."""
         with self._lock:
-            if not self.occupied[slot]:
-                raise KeyError(f"slot {slot} is not occupied")
+            return self._read_locked(slot, max_age_ticks)
+
+    def read_stamped(self, slot: int
+                     ) -> Tuple[float, Optional[float], Optional[float]]:
+        """``read(slot)`` plus the ``time.monotonic`` instants at which
+        the tick that first stamped that score took its snapshot and
+        stamped it (``None`` for both before the slot's first
+        scoring), read under one lock."""
+        with self._lock:
+            score = self._read_locked(slot, None)
             if self.scored_version[slot] < 0:
-                return float("nan")
-            if max_age_ticks is not None and (
-                    self.tick_count - self.last_scored_tick[slot]
-                    > max_age_ticks):
-                return float("nan")
-            return float(self.mirror[slot])
+                return score, None, None
+            return (score, float(self._tick_t0[slot]),
+                    float(self._tick_t1[slot]))
+
+    def _read_locked(self, slot: int,
+                     max_age_ticks: Optional[int]) -> float:
+        if not self.occupied[slot]:
+            raise KeyError(f"slot {slot} is not occupied")
+        if self.scored_version[slot] < 0:
+            return float("nan")
+        if max_age_ticks is not None and (
+                self.tick_count - self.last_scored_tick[slot]
+                > max_age_ticks):
+            return float("nan")
+        return float(self.mirror[slot])
 
     def wait_scored(self, slot: int, version: int,
                     timeout: float = 1.0) -> bool:
@@ -790,8 +836,10 @@ class SlotTicker:
             return self._beat
 
     def _run(self, epoch: int) -> None:
-        while not self._stop.wait(self.interval):
-            if not self._is_current(epoch):
+        while True:
+            with _spans.phase("slots.ticker.sleep"):
+                stopped = self._stop.wait(self.interval)
+            if stopped or not self._is_current(epoch):
                 return
             hook = self.before_tick
             if hook is not None:
@@ -805,7 +853,8 @@ class SlotTicker:
             if not self._is_current(epoch):
                 return
             try:
-                self.engine.tick()
+                with _spans.phase("slots.tick"):
+                    self.engine.tick()
             except Exception:
                 log.exception("slot tick failed; ticker continues")
             self._beat_now(epoch)

@@ -258,7 +258,6 @@ def test_recorder_attribution_and_coverage():
     measured = sum(att["stage_seconds"].values())
     assert att["coverage"] == pytest.approx(measured / att["e2e_seconds"])
     assert 0.0 < att["coverage"] <= 1.0 + 1e-9
-    assert rec.e2e_quantile(50) == pytest.approx(0.55, rel=REL_ERR_BOUND)
 
 
 def test_server_emits_spans_with_failure_statuses():
