@@ -11,9 +11,11 @@ sensors.  Two implementations share semantics:
   ``[n_patients, channels, capacity]`` buffer per modality) updated by
   compiled steps, the JAX-native analogue of the paper's Ray stateful
   actors.  ``DeviceIngest`` wraps them into the serving pipeline's
-  device-resident ingest stage: 250 Hz chunks land via ``ingest_chunk``
-  (a pow2 chunk-size ladder keeps the compiled-variant count bounded
-  under mixed-rate feeds) and a closed observation window is handed to
+  device-resident ingest stage: 250 Hz packets are staged on the host
+  and committed to the rings in batches by one program, longer chunks
+  go in one at a time on a pow2 size ladder (so the compiled-variant
+  count stays bounded under mixed-rate feeds), and a closed
+  observation window is handed to
   the ensemble as a ``DeviceWindowRef`` — three host integers per
   modality, NO host-side sample marshaling.  The flush side
   (``EnsembleService.predict_batch``) gathers the referenced windows
@@ -25,6 +27,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import threading
 from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
@@ -116,8 +119,8 @@ def ring_wrap(cap: int) -> int:
 def ingest_step(state: AggState, patient: jax.Array,
                 samples: jax.Array) -> AggState:
     """Append samples [channels, k] for one patient (ring semantics).
-    Retraces per distinct ``k`` — prefer ``ingest_chunk`` on the
-    serving path, which pads to a static size ladder."""
+    Retraces per distinct ``k``: the plain one-packet-at-a-time
+    reference that the batched ``_ingest_padded`` is checked against."""
     cap = state.buf.shape[-1]
     k = samples.shape[-1]
     idx = (state.write_idx[patient] + jnp.arange(k)) % cap
@@ -144,18 +147,31 @@ def chunk_rung(k: int) -> int:
     return pow2_rung(k)
 
 
+#: chunks of at most this many samples (0.5 s of 250 Hz ECG) are staged
+#: on the host and reach the ring in batches; longer ones go straight in
+PACKET_RUNG = 128
+#: staged packets per ring per commit: the batch ``_ingest_padded`` runs at
+STAGE_ROWS = 64
+
+
 @jax.jit
-def _ingest_padded(state: AggState, patient: jax.Array,
-                   samples: jax.Array, n_valid: jax.Array) -> AggState:
-    """Ladder-shaped ingest step: ``samples`` is [channels, rung] with
-    only the first ``n_valid`` columns real; pad lanes scatter to an
-    out-of-bounds ring position and are dropped, so the ring never sees
-    the padding."""
+def _ingest_padded(state: AggState, samples: jax.Array,
+                   patient: jax.Array, start: jax.Array,
+                   n_valid: jax.Array) -> AggState:
+    """Batched ring update: row ``b`` of ``samples`` [B, channels, rung]
+    holds ``n_valid[b]`` real samples for ``patient[b]``, written from
+    ring position ``start[b]`` on.  Pad lanes and pad rows
+    (``n_valid == 0``) scatter to the out-of-bounds position ``cap`` and
+    are dropped, so the ring never sees the padding.  ``write_idx`` and
+    ``total`` advance by scatter-add over ``patient``, so a patient's
+    rows add up; no patient may get more than ``cap`` samples in one
+    call, or their positions would collide."""
     cap = state.buf.shape[-1]
     lane = jnp.arange(samples.shape[-1])
-    pos = (state.write_idx[patient] + lane) % cap
-    pos = jnp.where(lane < n_valid, pos, cap)          # OOB -> dropped
-    buf = state.buf.at[patient, :, pos].set(samples.T, mode="drop")
+    pos = (start[:, None] + lane) % cap
+    pos = jnp.where(lane < n_valid[:, None], pos, cap)  # OOB -> dropped
+    buf = state.buf.at[patient[:, None], :, pos].set(
+        jnp.swapaxes(samples, 1, 2), mode="drop")
     return AggState(
         buf=buf,
         write_idx=state.write_idx.at[patient].add(n_valid)
@@ -163,22 +179,26 @@ def _ingest_padded(state: AggState, patient: jax.Array,
         total=state.total.at[patient].add(n_valid))
 
 
-def ingest_chunk(state: AggState, patient: int,
-                 samples: np.ndarray) -> AggState:
-    """Append a variable-length chunk through the pow2 size ladder:
-    one compiled variant per rung, not per chunk length."""
+def ingest_chunk(state: AggState, patient: int, samples: np.ndarray,
+                 start: Optional[int] = None) -> AggState:
+    """Append one variable-length chunk as a batch of one, right-padded
+    to its pow2 rung: one compiled variant per rung, not per chunk
+    length.  ``start`` is the ring position of its first sample
+    (``write_idx[patient] % cap``, read back from the device when not
+    given)."""
     samples = np.atleast_2d(np.asarray(samples, np.float32))
-    k = samples.shape[-1]
+    c, k = samples.shape
     cap = state.buf.shape[-1]
     if k > cap:
         raise ValueError(f"chunk of {k} samples exceeds ring capacity "
                          f"{cap}")
-    rung = chunk_rung(k)
-    if rung != k:
-        samples = np.pad(samples, ((0, 0), (0, rung - k)))
-    return _ingest_padded(state, jnp.asarray(patient, jnp.int32),
-                          jnp.asarray(samples),
-                          jnp.asarray(k, jnp.int32))
+    if start is None:
+        start = int(state.write_idx[patient]) % cap
+    row = np.zeros((1, c, chunk_rung(k)), np.float32)
+    row[0, :, :k] = samples
+    return _ingest_padded(state, row, np.array([patient], np.int32),
+                          np.array([start], np.int32),
+                          np.array([k], np.int32))
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
@@ -249,9 +269,51 @@ class DeviceWindowRef(NamedTuple):
         return np.asarray(win[0])
 
 
+class _Stage:
+    """One ring's host staging area: packets wait here, in the row
+    layout of ``_ingest_padded``, for the next commit."""
+
+    __slots__ = ("shape", "samples", "patient", "start", "n_valid", "n",
+                 "per_patient")
+
+    def __init__(self, channels: int, width: int):
+        self.shape = (STAGE_ROWS, channels, width)
+        self._fresh()
+
+    def _fresh(self) -> None:
+        self.samples = np.zeros(self.shape, np.float32)
+        self.patient = np.zeros(STAGE_ROWS, np.int32)
+        self.start = np.zeros(STAGE_ROWS, np.int32)
+        self.n_valid = np.zeros(STAGE_ROWS, np.int32)
+        self.n = 0
+        self.per_patient: Dict[int, int] = {}
+
+    def fits(self, patient: int, k: int, cap: int) -> bool:
+        """Room for a ``k``-sample packet of ``patient``: a free row, and
+        the patient's staged samples stay within the ring's capacity."""
+        return (self.n < STAGE_ROWS
+                and self.per_patient.get(patient, 0) + k <= cap)
+
+    def add(self, patient: int, start: int, samples: np.ndarray) -> None:
+        i, k = self.n, samples.shape[-1]
+        self.samples[i, :, :k] = samples
+        self.patient[i] = patient
+        self.start[i] = start
+        self.n_valid[i] = k
+        self.per_patient[patient] = self.per_patient.get(patient, 0) + k
+        self.n = i + 1
+
+    def take(self) -> Tuple[np.ndarray, ...]:
+        """Hand the rows over and start on fresh arrays: the program may
+        still read the old ones after the call that took them returns."""
+        out = (self.samples, self.patient, self.start, self.n_valid)
+        self._fresh()
+        return out
+
+
 class DeviceIngest:
     """Device-resident multi-patient ingest: one ``AggState`` ring per
-    modality, fed by the compiled pow2-ladder ``ingest_chunk``.
+    modality, fed in batches by ``_ingest_padded``.
 
     Window accounting stays on the host as plain integers (samples fed
     per patient, high-water mark at the last window close); the samples
@@ -265,17 +327,24 @@ class DeviceIngest:
     ref enqueued behind a busy server stays readable while the next
     window's samples stream in underneath it.
 
-    Concurrency contract: every ingest step is a FUNCTIONAL update —
-    ``self.states`` is replaced, never mutated — so a flush thread's
-    snapshot of ``states[m]`` stays valid (and immutable) while ingest
-    keeps advancing, with no locks.  The cost is that the jitted
-    scatter cannot donate its input buffer (a donated ring would
-    invalidate exactly those in-flight flush snapshots), so on the CPU
-    backend each chunk pays an O(n_patients * channels * cap) ring
-    copy.  The flush side — this PR's target — never sees that cost;
-    amortizing the feed side (per-patient ring stripes so a chunk
-    rewrites only its own [channels, cap] slice, or a batched
-    multi-patient step) is the ROADMAP's batched-ingest follow-up.
+    Staging: an ``ingest`` of at most ``PACKET_RUNG`` samples is host
+    work only.  It copies the packet into the modality's stage with the
+    ring position it starts at (``fed % cap``, the position
+    ``write_idx`` gives, since the wrap is a multiple of ``cap``).  A
+    commit writes the whole stage with one upload and one call of
+    ``_ingest_padded``.  It runs when someone reads the rings (``states``
+    commits first), and in line when a packet finds its stage full or
+    would take a patient past ``cap`` samples in one commit.  A longer
+    chunk commits the stage, then goes in as a batch of one.
+
+    Concurrency contract: one lock covers the stage append, the stage
+    swap and the replacement of ``states[m]``, so commits apply in
+    ingest order.  Every update is FUNCTIONAL — ``states[m]`` is
+    replaced, never mutated, and the program does not donate the ring
+    — so a reader's snapshot of ``states[m]`` (a tick or flush in
+    flight) stays valid and immutable while ingest keeps advancing.
+    ``fed`` moves under the same lock as the stage, so a ring read
+    through ``states`` never holds samples that ``fed`` does not count.
     """
 
     def __init__(self, modalities: List[ModalitySpec],
@@ -284,26 +353,70 @@ class DeviceIngest:
         self.modalities = {m.name: m for m in modalities}
         self.window = window_seconds
         self.n_patients = n_patients
-        self.states: Dict[str, AggState] = {}
+        self._lock = threading.Lock()
+        self._states: Dict[str, AggState] = {}
+        self._stage: Dict[str, _Stage] = {}
+        self.cap: Dict[str, int] = {}
         self.want: Dict[str, int] = {}
         self.fed: Dict[str, np.ndarray] = {}
         self.mark: Dict[str, np.ndarray] = {}
+        self._stats = {"commits": 0, "packets": 0, "full": 0, "direct": 0}
         for m in modalities:
             want = max(1, int(round(m.rate_hz * window_seconds)))
             cap = chunk_rung(max(2, int(np.ceil(
                 capacity_windows * want))))          # pow2: wrap-exact
-            self.states[m.name] = agg_init(n_patients, m.channels, cap)
+            self._states[m.name] = agg_init(n_patients, m.channels, cap)
+            self._stage[m.name] = _Stage(m.channels, min(PACKET_RUNG, cap))
+            self.cap[m.name] = cap
             self.want[m.name] = want
             self.fed[m.name] = np.zeros(n_patients, np.int64)
             self.mark[m.name] = np.zeros(n_patients, np.int64)
         self.window_start: List[Optional[float]] = [None] * n_patients
+        self._warm_commit()
+
+    @property
+    def states(self) -> Dict[str, AggState]:
+        """The rings, with every staged packet committed first: the one
+        way to read them."""
+        with self._lock:
+            for name in self._stage:
+                self._commit_locked(name)
+            return self._states
+
+    def _commit_locked(self, name: str, full: bool = False) -> None:
+        stage = self._stage[name]
+        if not stage.n:
+            return
+        with _spans.phase("ingest.commit"):
+            n = stage.n
+            self._states[name] = _ingest_padded(self._states[name],
+                                                *stage.take())
+        self._stats["commits"] += 1
+        self._stats["packets"] += n
+        self._stats["full"] += full
+
+    def _warm_commit(self) -> None:
+        """Compile the commit program at each stage's one shape, off the
+        serving path (the empty stage leaves the ring as it is)."""
+        for name, stage in self._stage.items():
+            jax.block_until_ready(_ingest_padded(self._states[name],
+                                                 *stage.take()))
+
+    def stats(self) -> Dict[str, float]:
+        """Commits of the stage, packets they wrote, commits forced by a
+        full stage, chunks written straight in, packets per commit."""
+        with self._lock:
+            out: Dict[str, float] = dict(self._stats)
+        out["packets_per_commit"] = (out["packets"] / out["commits"]
+                                     if out["commits"] else 0.0)
+        return out
 
     def grow(self, n_patients: int) -> None:
         """Grow the census to ``n_patients`` ring rows (no-op when
         already large enough).  Each modality's ring is replaced by a
         zero-padded copy along the patient axis — a FUNCTIONAL update,
         so an in-flight flush's snapshot of the old (smaller) state
-        stays valid, exactly like ``ingest``'s replacement contract.
+        stays valid, exactly like a commit's replacement contract.
         Existing rows keep their samples and window accounting bitwise;
         new rows start empty.  Like ``ingest``, growth assumes a single
         feeding thread per modality (the ``SlotEngine`` serializes its
@@ -311,23 +424,40 @@ class DeviceIngest:
         if n_patients <= self.n_patients:
             return
         add = n_patients - self.n_patients
-        for name, st in self.states.items():
-            self.states[name] = AggState(
-                buf=jnp.pad(st.buf, ((0, add), (0, 0), (0, 0))),
-                write_idx=jnp.pad(st.write_idx, (0, add)),
-                total=jnp.pad(st.total, (0, add)))
-            self.fed[name] = np.pad(self.fed[name], (0, add))
-            self.mark[name] = np.pad(self.mark[name], (0, add))
-        self.window_start.extend([None] * add)
-        self.n_patients = n_patients
+        with self._lock:
+            for name in self._stage:
+                self._commit_locked(name)
+                st = self._states[name]
+                self._states[name] = AggState(
+                    buf=jnp.pad(st.buf, ((0, add), (0, 0), (0, 0))),
+                    write_idx=jnp.pad(st.write_idx, (0, add)),
+                    total=jnp.pad(st.total, (0, add)))
+                self.fed[name] = np.pad(self.fed[name], (0, add))
+                self.mark[name] = np.pad(self.mark[name], (0, add))
+            self.window_start.extend([None] * add)
+            self.n_patients = n_patients
+            self._warm_commit()
 
     def ingest(self, t: float, patient: int, modality: str,
                samples: np.ndarray) -> None:
         with _spans.phase(f"ingest.{modality}"):
             samples = np.atleast_2d(np.asarray(samples, np.float32))
-            self.states[modality] = ingest_chunk(
-                self.states[modality], patient, samples)
-            self.fed[modality][patient] += samples.shape[-1]
+            k = samples.shape[-1]
+            cap = self.cap[modality]
+            stage = self._stage[modality]
+            with self._lock:
+                fed = self.fed[modality]
+                start = int(fed[patient] % cap)
+                if k <= stage.shape[-1]:
+                    if not stage.fits(patient, k, cap):
+                        self._commit_locked(modality, full=True)
+                    stage.add(patient, start, samples)
+                else:
+                    self._commit_locked(modality)
+                    self._states[modality] = ingest_chunk(
+                        self._states[modality], patient, samples, start)
+                    self._stats["direct"] += 1
+                fed[patient] += k
             if self.window_start[patient] is None:
                 self.window_start[patient] = t
 
@@ -372,8 +502,7 @@ class DeviceIngest:
         (and count) new queries rather than let them go
         stale-then-NaN."""
         if modality is not None:
-            st = self.states[modality]
-            cap = int(st.buf.shape[-1])
+            cap = self.cap[modality]
             mark = int(self.mark[modality][patient])
             fed = int(self.fed[modality][patient])
             oldest = max(0, mark - self.want[modality])

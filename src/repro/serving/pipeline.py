@@ -641,7 +641,7 @@ class EnsembleService:
         ``_combine`` plain dicts.  Without CPU-side models the refs
         pass through untouched and nothing is ever read back."""
         if self.vitals_model is None \
-                or "vitals" not in batch[0].ingest.states:
+                or "vitals" not in batch[0].ingest.modalities:
             return batch
         ingest = batch[0].ingest
         st = ingest.states["vitals"]
@@ -826,9 +826,9 @@ class StreamingPipeline:
     """Stateful aggregators + the ensemble service, driven by a stream.
 
     ``device_ingest=True`` replaces the per-sample python tuple buffers
-    with ``serving.aggregator.DeviceIngest``: 250 Hz chunks land in
-    device-resident ring buffers via the compiled pow2-ladder
-    ``ingest_chunk``, and a closed window is served as a
+    with ``serving.aggregator.DeviceIngest``: 250 Hz chunks are staged
+    on the host and committed to device-resident ring buffers in
+    batches, and a closed window is served as a
     ``DeviceWindowRef`` — the ensemble's flush gathers the samples on
     device, so the ingest->inference path never marshals waveforms
     through the host.  ``PatientAggregator`` (the default) is kept as

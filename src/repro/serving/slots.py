@@ -152,7 +152,8 @@ class TickReport:
     (snapshot, gather, dispatch, fold, readback, combine, stamp), run
     one after another.  On a tick that scored a slot ``seconds`` ends
     where the stamp begins, so every phase but the stamp lies inside
-    it."""
+    it.  A tick whose gather committed staged ingest packets also has
+    ``ingest.commit``, which lies inside ``slots.tick.gather``."""
     tick: int                       # tick ordinal after this tick
     n_scored: int                   # occupied slots scored this tick
     n_stale: int                    # occupied slots skipped (ring overrun)
@@ -194,7 +195,7 @@ class SlotEngine:
                              "tick gathers windows on device)")
         if not service.members:
             raise ValueError("SlotEngine needs at least one zoo member")
-        if "ecg" not in ingest.states:
+        if "ecg" not in ingest.modalities:
             raise ValueError("SlotEngine needs an 'ecg' ingest ring")
         _quiet_cpu_donation()
         self.service = service
@@ -234,9 +235,9 @@ class SlotEngine:
         self.occupied = np.zeros(self.n_slots, bool)
         self.has_window = np.zeros(self.n_slots, bool)
         self._ends = {m: np.zeros(self.n_slots, np.int64)
-                      for m in ingest.states}
+                      for m in ingest.modalities}
         self._valid = {m: np.zeros(self.n_slots, np.int64)
-                       for m in ingest.states}
+                       for m in ingest.modalities}
         self._extra: List[Dict] = [{} for _ in range(self.n_slots)]
         self._close_version = np.zeros(self.n_slots, np.int64)
         self.scored_version = np.full(self.n_slots, -1, np.int64)
@@ -455,11 +456,11 @@ class SlotEngine:
         the vitals ring iff the tick's side-model readback uses it."""
         need = {"ecg": max(self._lens)}
         if self.service.vitals_model is not None \
-                and "vitals" in self.ingest.states:
+                and "vitals" in self.ingest.modalities:
             need["vitals"] = self.ingest.want["vitals"]
         stale = np.zeros(self.n_slots, bool)
         for m, l_need in need.items():
-            cap = int(self.ingest.states[m].buf.shape[-1])
+            cap = self.ingest.cap[m]
             fed = self.ingest.fed[m][:self.n_slots]
             oldest = ends[m] - np.minimum(valid[m], l_need)
             stale |= occ & ((fed - oldest) > cap)
@@ -593,7 +594,7 @@ class SlotEngine:
 
             vit_rows = None
             if svc.vitals_model is not None \
-                    and "vitals" in self.ingest.states:
+                    and "vitals" in self.ingest.modalities:
                 vst = self.ingest.states["vitals"]
                 vcap = vst.buf.shape[-1]
                 vej = jnp.asarray(np.pad(
@@ -768,7 +769,7 @@ class SlotEngine:
         never pays XLA compile on the serving path."""
         self.ingest.warm_gather(self._lens, batch_sizes=(self._Spad,))
         if self.service.vitals_model is not None \
-                and "vitals" in self.ingest.states:
+                and "vitals" in self.ingest.modalities:
             self.ingest.warm_gather(
                 (self.ingest.want["vitals"],),
                 batch_sizes=(self._Spad,), modality="vitals")

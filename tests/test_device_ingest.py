@@ -20,6 +20,7 @@
 import os
 import subprocess
 import sys
+import threading
 
 import numpy as np
 import pytest
@@ -567,3 +568,269 @@ def test_bench_ingest_smoke_schema():
                        verbose=False, write_json=False)
     check_ingest_schema(out)
     assert out["h2d_reduction_x"] == pytest.approx(4.0)
+
+
+# ------------------------------------------------------- staged ingest
+# name: (ring, beds, packet lengths drawn per packet, samples per bed,
+#        read the rings every n packets (0: only at the end), whether a
+#        full stage or a bed's capacity must force an in-line commit)
+STAGE_CASES = {
+    "interleaved": (ModalitySpec("ecg", 250.0, 3), 4, (1, 7, 50, 125, 128),
+                    300, 3, False),
+    "wrap": (ModalitySpec("ecg", 250.0, 3), 2, (125,), 1500, 0, True),
+    "full_stage": (ModalitySpec("ecg", 250.0, 3), 80, (7,), 14, 0, True),
+    "long_chunk": (ModalitySpec("ecg", 250.0, 3), 3, (100, 300), 800, 4,
+                   False),
+    "vitals": (ModalitySpec("vitals", 1.0, 7), 3, (1, 2, 3), 150, 0, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(STAGE_CASES))
+def test_staged_ring_matches_packet_at_a_time(case, rng):
+    """Packets staged on the host and committed in batches leave the
+    ring, its write index and its totals bitwise where the per-length
+    ``ingest_step``, one packet at a time, leaves them."""
+    spec, beds, lens, per_bed, read_every, forced = STAGE_CASES[case]
+    window = 30.0 if spec.name == "vitals" else 1.0
+    di = DeviceIngest([spec], n_patients=beds, window_seconds=window)
+    one = agg_init(beds, spec.channels, di.cap[spec.name])
+    fed = np.zeros(beds, int)
+    i = 0
+    while (fed < per_bed).any():
+        for p in np.flatnonzero(fed < per_bed):
+            k = min(int(rng.choice(lens)), per_bed - int(fed[p]))
+            x = rng.standard_normal((spec.channels, k)).astype(np.float32)
+            di.ingest(0.0, int(p), spec.name, x)
+            one = ingest_step(one, jnp.asarray(int(p)), jnp.asarray(x))
+            fed[p] += k
+            i += 1
+            if read_every and i % read_every == 0:
+                di.states
+    got = di.states[spec.name]
+    np.testing.assert_array_equal(np.asarray(got.buf), np.asarray(one.buf))
+    np.testing.assert_array_equal(np.asarray(got.write_idx),
+                                  np.asarray(one.write_idx))
+    np.testing.assert_array_equal(np.asarray(got.total), fed)
+    np.testing.assert_array_equal(di.fed[spec.name], fed)
+    stats = di.stats()
+    assert (stats["full"] > 0) == forced
+    assert (stats["direct"] > 0) == (case == "long_chunk")
+
+
+@pytest.mark.parametrize("reader", ["tick", "flush", "host_window"])
+def test_ref_closed_after_staged_packets_reads_them(reader, zoo_members,
+                                                    rng):
+    """A window whose packets are all still staged when it closes is
+    read with those samples by every reader of the rings: a slot tick,
+    a flush and the host read-back."""
+    from repro.serving.slots import SlotEngine
+    svc = EnsembleService(zoo_members)
+    di = DeviceIngest([ModalitySpec("ecg", 250.0, 3)], n_patients=8,
+                      window_seconds=1.0)
+    windows = [rng.standard_normal((3, 250)).astype(np.float32)
+               for _ in range(8)]
+    refs = []
+    for p, w in enumerate(windows):
+        for off in (0, 125):
+            di.ingest(off / 250.0, p, "ecg", w[:, off:off + 125])
+        refs.append(di.close_window(p, 1.0))
+    assert di.stats()["commits"] == 0          # all 16 packets staged
+    if reader == "host_window":
+        for r, w in zip(refs, windows):
+            np.testing.assert_array_equal(r.host_window("ecg"), w)
+    else:
+        want = np.asarray(svc.predict_batch([{"ecg": w} for w in windows]))
+        if reader == "tick":
+            eng = SlotEngine(svc, di)
+            for r in refs:
+                eng.update(r)
+            assert eng.tick().n_scored == 8
+            got = np.asarray([eng.read(p) for p in range(8)])
+        else:
+            got = np.asarray(svc.predict_batch(refs))
+        assert np.array_equal(got, want)
+    assert di.stats()["commits"] == 1 and di.stats()["packets"] == 16
+
+
+def test_headroom_commits_nothing():
+    """The backpressure signal needs only host integers and the ring
+    capacities: it leaves staged packets staged."""
+    di = DeviceIngest([ModalitySpec("ecg", 250.0, 3),
+                       ModalitySpec("vitals", 1.0, 7)],
+                      n_patients=2, window_seconds=1.0)
+    di.ingest(0.0, 0, "ecg", np.zeros((3, 125), np.float32))
+    di.ingest(0.0, 0, "vitals", np.zeros((7, 1), np.float32))
+    di.close_window(0, 1.0)
+    assert di.headroom_by_modality(0) == {"ecg": 512 - 125, "vitals": 1}
+    assert di.headroom(0) == 1.0
+    assert di.stats()["commits"] == 0
+
+
+def test_commit_program_compiles_once_at_setup(rng):
+    """Building the ingest compiles the commit program at the stage's
+    one shape; no mix of packet lengths up to the rung, read or forced
+    commit compiles it again."""
+    from repro.serving.aggregator import PACKET_RUNG, _ingest_padded
+    di = DeviceIngest([ModalitySpec("ecg", 250.0, 3)], n_patients=5,
+                      window_seconds=1.0)
+    compiled = _ingest_padded._cache_size()
+    lens = list(range(1, PACKET_RUNG + 1))
+    rng.shuffle(lens)
+    for i, k in enumerate(lens):
+        di.ingest(0.0, i % 5, "ecg", np.zeros((3, k), np.float32))
+        if i % 50 == 49:
+            di.states
+    di.states
+    assert _ingest_padded._cache_size() == compiled
+    stats = di.stats()
+    assert stats["full"] > 0 and stats["packets"] == PACKET_RUNG
+
+
+def test_stats_count_commits_and_packets():
+    di = DeviceIngest([ModalitySpec("ecg", 250.0, 3)], n_patients=70,
+                      window_seconds=1.0)
+    z = np.zeros((3, 10), np.float32)
+    for p in range(70):                 # the 65th finds the stage full
+        di.ingest(0.0, p, "ecg", z)
+    assert di.stats() == {"commits": 1, "packets": 64, "full": 1,
+                          "direct": 0, "packets_per_commit": 64.0}
+    # a chunk past the rung commits the six staged first, then goes in
+    di.ingest(0.0, 0, "ecg", np.zeros((3, 300), np.float32))
+    assert di.stats() == {"commits": 2, "packets": 70, "full": 1,
+                          "direct": 1, "packets_per_commit": 35.0}
+    di.states                           # nothing staged: no commit
+    assert di.stats()["commits"] == 2
+    for _ in range(3):
+        di.ingest(0.0, 1, "ecg", z)
+    assert di.stats()["commits"] == 2   # staged, not written
+    np.testing.assert_array_equal(np.asarray(di.states["ecg"].total),
+                                  di.fed["ecg"])
+    assert di.stats()["commits"] == 3 and di.stats()["packets"] == 73
+
+
+def test_ingest_and_tick_threads_match_serial_replay(zoo_members, rng):
+    """One thread stages packets and closes windows while another ticks
+    back to back: every close is scored exactly as a serial replay of
+    the same stream scores it, with no error and no stale slot.  The
+    feeder waits for each round to be scored before the next, so no
+    window is overwritten under a tick (the ring holds two)."""
+    from repro.serving.slots import SlotEngine
+    beds, rounds, pk = 4, 5, 25
+    stream = rng.standard_normal((beds, 3, rounds * 250)).astype(np.float32)
+    svc = EnsembleService(zoo_members)
+
+    def build():
+        di = DeviceIngest([ModalitySpec("ecg", 250.0, 3)], n_patients=beds,
+                          window_seconds=1.0)
+        return di, SlotEngine(svc, di)
+
+    def feed_round(di, eng, r):
+        for off in range(r * 250, (r + 1) * 250, pk):
+            for p in range(beds):
+                di.ingest(off / 250.0, p, "ecg", stream[p, :, off:off + pk])
+        return [eng.update(di.close_window(p, r + 1.0))
+                for p in range(beds)]
+
+    def scored(reports):
+        return {(int(s), int(v)): float(x) for rep in reports
+                for s, v, x in zip(rep.stamped, rep.versions, rep.scores)}
+
+    di, eng = build()
+    serial = []
+    for r in range(rounds):
+        feed_round(di, eng, r)
+        serial.append(eng.tick())
+
+    di, eng = build()
+    reports, errors, done = [], [], threading.Event()
+
+    def feeder():
+        try:
+            for r in range(rounds):
+                versions = feed_round(di, eng, r)
+                for p, v in enumerate(versions):
+                    assert eng.wait_scored(p, v, timeout=60.0), (p, v)
+        except BaseException as e:      # reported on the test thread
+            errors.append(e)
+        finally:
+            done.set()
+
+    def ticker():
+        try:
+            while not done.is_set():
+                reports.append(eng.tick())
+        except BaseException as e:
+            errors.append(e)
+
+    threads = [threading.Thread(target=feeder), threading.Thread(target=ticker)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=240.0)
+    assert not any(t.is_alive() for t in threads)
+    assert not errors, errors
+    assert all(rep.n_stale == 0 and not rep.skipped for rep in reports)
+    want = scored(serial)
+    assert len(want) == beds * rounds
+    assert scored(reports) == want
+    assert di.stats()["packets"] == beds * rounds * 250 // pk
+
+
+def test_staged_ingest_under_concurrent_feeders_and_readers(rng):
+    """Twelve feeder threads (two beds each) stage packets while four
+    readers commit the stage over and over, with the interpreter
+    switching threads every few microseconds: no staged row, ``fed``
+    count or ring update is lost.  Each bed's ring ends as a plain
+    replay of its own stream leaves it."""
+    beds, feeders, per_bed = 24, 12, 1500
+    di = DeviceIngest([ModalitySpec("ecg", 250.0, 3)], n_patients=beds,
+                      window_seconds=1.0)
+    cap = di.cap["ecg"]
+    streams = rng.standard_normal((beds, 3, per_bed)).astype(np.float32)
+    cuts = {p: np.cumsum(rng.integers(1, 129, size=per_bed))
+            for p in range(beds)}
+    errors, done = [], threading.Event()
+
+    def feed(mine):
+        try:
+            for p in mine:
+                edges = [0] + [int(c) for c in cuts[p] if c < per_bed]
+                for a, b in zip(edges, edges[1:] + [per_bed]):
+                    di.ingest(0.0, p, "ecg", streams[p, :, a:b])
+        except BaseException as e:      # reported on the test thread
+            errors.append(e)
+
+    def read():
+        try:
+            while not done.is_set():
+                di.states
+        except BaseException as e:
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        fs = [threading.Thread(target=feed, args=(range(f, beds, feeders),))
+              for f in range(feeders)]
+        rs = [threading.Thread(target=read) for _ in range(4)]
+        for t in fs + rs:
+            t.start()
+        for t in fs:
+            t.join(timeout=120.0)
+        done.set()
+        for t in rs:
+            t.join(timeout=30.0)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in fs + rs)
+    assert not errors, errors
+    want = np.zeros((beds, 3, cap), np.float32)
+    for i in range(per_bed):
+        want[:, :, i % cap] = streams[:, :, i]
+    st = di.states["ecg"]
+    np.testing.assert_array_equal(np.asarray(st.buf), want)
+    np.testing.assert_array_equal(np.asarray(st.total), per_bed)
+    np.testing.assert_array_equal(di.fed["ecg"], per_bed)
+    stats = di.stats()
+    assert stats["commits"] > 1 and stats["packets"] == sum(
+        len([c for c in cuts[p] if c < per_bed]) + 1 for p in range(beds))
