@@ -4,11 +4,15 @@ return the result line.
 
 The system under test is the program's slot-engine server:
 ``EnsembleServer(engine="slots")`` over a ``SlotEngine`` over a
-``DeviceIngest``, with the configuration's zoo (and side models) in an
-``EnsembleService``, LPT-placed over the cell's chips when it has more
-than one.  Everything else here is the benchmark's own: the weights
-(``reference.init_zoo``), the traffic (``traffic.py``), the client
-clock, the reference and the reduction of the trace.
+``DeviceIngest``, with the configuration's members (and side models) in
+an ``EnsembleService``, LPT-placed over the cell's chips when it has
+more than one.  Everything else here is the benchmark's own: the
+traffic (``traffic.py``), the client clock, the reduction of the trace,
+and what the configuration names: its member family
+(``families/<family>.py``: members, weights, work counts, the groups
+and program names it reports) and its plain reference (``reference``).
+What is generic stays here: the rings, Eq. 5, the side models and
+their copies (``side_reference.py``), the limits and the control.
 
 Clock: ``time.monotonic`` throughout, the same clock the server stamps
 its spans with.  A query's latency runs from its close's *scheduled*
@@ -18,6 +22,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import gc
 import glob
 import importlib.util
@@ -36,8 +41,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 
-import flops as _flops                    # noqa: E402
-import reference as _ref                  # noqa: E402
+import side_reference as _side           # noqa: E402
 import trace_reduce as _tr                # noqa: E402
 import traffic as _traffic                # noqa: E402
 
@@ -47,6 +51,11 @@ FAIL_MS = 60_000.0       # latency given to a query that failed or never came
 CLIENT_POLL_S = 0.001    # the client's poll of the server's results
 TRACE_S = 3.0            # seconds traced in a --trace 1 run
 SLO_S = 1.0              # the paper's score deadline, the server's slo
+FAMILIES = os.path.join(HERE, "families")
+# what a member family module supplies (each family's docstring says
+# what each is)
+FAMILY_ROLES = ("members", "init", "program", "input_len", "step_flops",
+                "kernel_flops", "cost", "gap_groups", "PROGRAMS")
 
 
 class BenchError(RuntimeError):
@@ -79,16 +88,49 @@ def load_limits(workload: str) -> Dict:
         return json.load(f)
 
 
+def _load_module(name: str, path: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod          # dataclasses look their module up
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load_reader(name: str):
     mdir = os.path.join(HERE, "metrics")
     if mdir not in sys.path:
         sys.path.insert(0, mdir)
-    path = os.path.join(mdir, f"{name}.py")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load_module(f"bench_metric_{name}",
+                        os.path.join(mdir, f"{name}.py")).read
+
+
+def load_family(cfg: Dict):
+    """The member family the configuration names, from
+    ``FAMILIES/<family>.py``, with every role of ``FAMILY_ROLES``."""
+    name = cfg.get("family")
+    path = os.path.join(FAMILIES, f"{name}.py")
+    if not isinstance(name, str) or not os.path.isfile(path):
+        raise BenchError(f"no member family {name!r} in {FAMILIES}")
+    mod = _load_module(f"bench_family_{name}", path)
+    missing = [r for r in FAMILY_ROLES if not hasattr(mod, r)]
+    if missing:
+        raise BenchError(f"member family {name!r} lacks {missing}")
+    return mod
+
+
+def load_reference(cfg: Dict):
+    """The plain reference the configuration names (``reference``, a
+    file relative to ``bench/``): ``member_scores(params, members,
+    windows, *, dtype, precision, devices)``."""
+    path = os.path.join(HERE, str(cfg.get("reference")))
+    if not os.path.isfile(path):
+        raise BenchError(f"no reference {cfg.get('reference')!r} for "
+                         f"configuration {cfg.get('name')!r}")
+    mod = _load_module("bench_reference_" + os.path.splitext(
+        os.path.basename(path))[0], path)
+    if not hasattr(mod, "member_scores"):
+        raise BenchError(f"reference {path} has no member_scores")
+    return mod
 
 
 def peak_for(kind: str) -> Dict:
@@ -197,6 +239,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     cfg = cfg if cfg is not None else cfg0
     mix = mix if mix is not None else mix0
     chips = chips if chips is not None else int(cell["chips"])
+    fam = load_family(cfg)
+    ref = load_reference(cfg)
 
     import jax
     devices = jax.devices()
@@ -215,24 +259,21 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     logging.getLogger("jax").addHandler(clog)
 
     sys.path.insert(0, os.path.join(ROOT, "src"))
-    from repro.configs.ecg_zoo import EcgModelSpec, bucket_zoo
     from repro.models.tabular import LogisticRegression, VitalsForest
     from repro.obs.spans import SpanRecorder
     from repro.serving.aggregator import (DeviceIngest, DeviceWindowRef,
                                           ModalitySpec)
-    from repro.serving.pipeline import EnsembleService, ZooMember
+    from repro.serving.pipeline import EnsembleService
     from repro.serving.placement import grouped_lpt_placement
     from repro.serving.server import EnsembleServer
     from repro.serving.slots import SlotEngine
 
-    # ---- the zoo: weights made on the device from the seed
-    members = _ref.member_specs(cfg)
-    params = _ref.init_zoo(members, seed, device=devs[0])
+    # ---- the members: weights made on the device from the seed
+    members = fam.members(cfg)
+    params = fam.init(members, seed, device=devs[0])
     jax.block_until_ready(params)
     log(f"set-up: weights made at {time.monotonic() - t_start:.1f} s")
-    specs = [EcgModelSpec(m.name, m.lead, m.width, m.blocks, m.input_len,
-                          m.cardinality, m.kernel_size) for m in members]
-    zoo = [ZooMember(s, p) for s, p in zip(specs, params)]
+    served, stacks = fam.program(members, params)
 
     # ---- side models, fitted from the seed on the copied generators
     side = cfg.get("side_models") or {}
@@ -254,12 +295,11 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
 
     placement = None
     if chips > 1:
-        groups = list(bucket_zoo(specs).values())
-        costs = [_flops.member_macs(members[g[0]]) * len(g) for g in groups]
-        placement = grouped_lpt_placement(groups, costs, chips)
-        log(f"placement over {chips} chips (LPT on MACs): members per chip "
-            f"{[len(s) for s in placement.assignment]}")
-    service = EnsembleService(zoo, vitals_model=vit_model,
+        costs = [fam.cost(members[g[0]]) * len(g) for g in stacks]
+        placement = grouped_lpt_placement(stacks, costs, chips)
+        log(f"placement over {chips} chips (LPT on the family's costs): "
+            f"members per chip {[len(s) for s in placement.assignment]}")
+    service = EnsembleService(served, vitals_model=vit_model,
                               labs_model=lab_model, placement=placement,
                               devices=devs if chips > 1 else None)
 
@@ -353,7 +393,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
               for s in tracer.spans()] if tracer is not None else [])
     spad = engine._Spad
     n_buckets = len(service._buckets)
-    del server, engine, service, ingest, zoo, submit, client
+    del server, engine, service, ingest, served, submit, client
     gc.collect()
 
     # ---- what the window attempted, and what came back
@@ -378,8 +418,9 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
         log(f"threads left running after stop: {leaked}")
 
     # ---- correctness: a seeded sample of the finished queries
-    checks = _check(workload, cfg, tr, params, members, in_win, seed, devs,
-                    cohort, side, compare, control, live["compiles"], cols)
+    checks = _check(workload, cfg, fam, ref, tr, params, members, in_win,
+                    seed, devs, cohort, side, compare, control,
+                    live["compiles"], cols)
     ctl = checks.pop("_control", None)
     readings = checks.pop("_readings", None)
     correct = all(c["ok"] for c in checks.values())
@@ -408,8 +449,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
             "gen_lag": live["lag"], "spans": spans, "ticks": ticks_all,
             "side": side_s, "chips": chips, "spad": spad,
             "n_buckets": n_buckets,
-            "zoo_flops": _flops.zoo_flops(members),
-            "conv_flops": _flops.conv_flops(members),
+            "step_flops": fam.step_flops(members),
+            "kernel_flops": fam.kernel_flops(members),
             "peak_flops": peak_flops if peak_flops is not None
             else peak_for(d0.device_kind)["bf16_flops_per_s"],
         }
@@ -590,9 +631,11 @@ def _drain(queries: Dict[int, Query]) -> None:
         time.sleep(0.01)
 
 
-def _check(workload, cfg, tr, params, members, in_win, seed, devs, cohort,
-           side, compare, control, compiles, cols) -> Dict[str, Dict]:
-    """Compare a seeded sample of the served scores with the reference:
+def _check(workload, cfg, fam, ref_mod, tr, params, members, in_win, seed,
+           devs, cohort, side, compare, control, compiles,
+           cols) -> Dict[str, Dict]:
+    """Compare a seeded sample of the served scores with the
+    configuration's reference (``ref_mod``):
     the ensemble score the client held and every member's score in the
     tick that served it (``_gaps``), each held to its limit where the
     cell's limits name it.  Returns {name: {value, limit, ok}}, with the
@@ -609,7 +652,7 @@ def _check(workload, cfg, tr, params, members, in_win, seed, devs, cohort,
     want_n = min(compare, len(in_win))
     checks["compared"] = {"value": n, "limit": want_n, "ok": n >= want_n
                           and n > 0}
-    bucket_compiles = [c for c in compiles if c in ("fn", "jit(fn)")]
+    bucket_compiles = [c for c in compiles if c in fam.PROGRAMS]
     checks["bucket_compiles_in_window"] = {
         "value": len(bucket_compiles), "limit": 0,
         "ok": not bucket_compiles}
@@ -617,52 +660,37 @@ def _check(workload, cfg, tr, params, members, in_win, seed, devs, cohort,
         for name in limits:
             checks[name] = _gap_check(None, limits[name])
         return checks
-    L = members[0].input_len
-    wins = np.stack([tr.ecg[q.bed, :, tr.close_ends[q.bed, q.close] - L:
-                            tr.close_ends[q.bed, q.close]]
-                     for q in sample])
-    side_rows = []
-    if side:
-        vf = side["vitals_forest"]
-        rvit = _ref.VitalsForest(_traffic.N_VITALS, vf["n_trees"],
-                                 vf["max_depth"], seed).fit(
-            cohort["vitals"], cohort["label"])
-        lr = side["labs_logistic"]
-        rlab = _ref.LogisticRegression(lr["lr"], lr["steps"], lr["l2"],
-                                       seed).fit(cohort["labs"],
-                                                 cohort["label"])
-        W = int(cfg["window_s"]) * _traffic.VITALS_HZ
-        vits = np.stack([tr.vitals[q.bed, :, tr.close_vends[q.bed, q.close]
-                                   - W: tr.close_vends[q.bed, q.close]]
-                         for q in sample])
-        labs = np.stack([tr.labs[q.bed, q.close] for q in sample])
-        side_rows = [np.concatenate([rvit.predict_proba(v[None])
-                                     for v in vits]),
-                     np.concatenate([rlab.predict_proba(x[None])
-                                     for x in labs])]
+    L = fam.input_len(members)
+    groups = fam.gap_groups(members)
+    side_of = _side_scores(cfg, side, cohort, seed, tr)
+    # the member scores of the tick that stamped each served score, and
+    # the close whose window that tick scored: the query's own, or,
+    # where a later close of the bed arrived before any tick stamped
+    # it, the later close whose score the slot engine served
+    found = [_served_by(q, cols, side_of) for q in sample]
+    closes = [f[0] if f else q.close for q, f in zip(sample, found)]
+    wins = np.stack([tr.ecg[q.bed, :, tr.close_ends[q.bed, c] - L:
+                            tr.close_ends[q.bed, c]]
+                     for q, c in zip(sample, closes)])
+    sides = [side_of(q.bed, c) for q, c in zip(sample, closes)]
+    side_rows = [np.asarray(r) for r in zip(*sides)]
     t = time.monotonic()
     prec = cfg.get("matmul_precision", "default")
-    mat = _ref.member_scores(params, members, wins, devices=devs,
-                             precision=prec)
-    ref = _ref.ensemble_scores(mat, side_rows)
+    mat = ref_mod.member_scores(params, members, wins, devices=devs,
+                                precision=prec)
+    ref = _side.ensemble_scores(mat, side_rows)
     served = np.asarray([q.score for q in sample])
     log(f"reference: {n} windows x {len(members)} members at {prec} "
         f"precision in {time.monotonic() - t:.1f} s")
-    # the served score against Eq. 5 over the member scores of the tick
-    # that stamped it (the one of the close's ticks that agrees best)
-    got = []
-    for i, q in enumerate(sample):
-        comb = [abs(q.score - _eq5(c, side_rows, i))
-                for c in cols.get((q.bed, q.close + 1), [])]
-        if comb:
-            k = int(np.argmin(comb))
-            got.append((i, cols[(q.bed, q.close + 1)][k], comb[k]))
-    log(f"member scores found for {len(got)} of {n} compared closes")
+    got = [(i, f[1], f[2]) for i, f in enumerate(found) if f]
+    log(f"member scores found for {len(got)} of {n} compared closes, "
+        f"{sum(c != q.close for q, c in zip(sample, closes))} of them "
+        f"served with a later close's score")
     rows = [i for i, _, _ in got]
     served_mat = (np.stack([c for _, c, _ in got], axis=1) if got
                   else np.zeros((len(members), 0)))
     read = _gaps(served, served_mat, [g for _, _, g in got], ref,
-                 mat[:, rows], members)
+                 mat[:, rows], groups)
     log("readings: " + " ".join(f"{k}={v:.4g}" for k, v in read.items()
                                 if v is not None))
     for name in limits:
@@ -674,27 +702,27 @@ def _check(workload, cfg, tr, params, members, in_win, seed, devs, cohort,
     if control:
         # the reference in bfloat16, put in the program's place on the
         # same windows, through the same comparison
-        cmat = _ref.member_scores(params, members, wins, devices=devs,
-                                  dtype="bfloat16")
-        ctl = np.asarray([_eq5(cmat[:, i], side_rows, i) for i in range(n)])
-        cread = _gaps(ctl, cmat, [0.0] * n, ref, mat, members)
+        cmat = ref_mod.member_scores(params, members, wins, devices=devs,
+                                     dtype="bfloat16")
+        ctl = np.asarray([_eq5(cmat[:, i], sides[i]) for i in range(n)])
+        cread = _gaps(ctl, cmat, [0.0] * n, ref, mat, groups)
         cchecks = {k: v for k, v in checks.items() if k[0] != "_"}
         for name in limits:
             cchecks[name] = _gap_check(cread.get(name), limits[name])
         # both sides again against the reference at the highest
         # precision: readings for PERF.md, not compared
-        hmat = _ref.member_scores(params, members, wins, devices=devs,
-                                  precision="highest")
-        href = _ref.ensemble_scores(hmat, side_rows)
+        hmat = ref_mod.member_scores(params, members, wins, devices=devs,
+                                     precision="highest")
+        href = _side.ensemble_scores(hmat, side_rows)
         checks["_control"] = {
             "correct": all(c["ok"] for c in cchecks.values()),
             "checks": {n: {"value": c["value"], "limit": c["limit"]}
                        for n, c in cchecks.items()},
             "readings": cread,
             "highest": {"program": _gaps(served, served_mat, [], href,
-                                         hmat[:, rows], members),
+                                         hmat[:, rows], groups),
                         "control": _gaps(ctl, cmat, [], href, hmat,
-                                         members)},
+                                         groups)},
             # each member's mean gap, program and control
             "per_member": [np.abs(served_mat - mat[:, rows]).mean(1).tolist(),
                            np.abs(cmat - mat).mean(1).tolist()]}
@@ -702,25 +730,72 @@ def _check(workload, cfg, tr, params, members, in_win, seed, devs, cohort,
 
 
 # the numbers a cell may compare, each held to its limit where the
-# cell's limits file names it; ``member_mean_gap`` also comes per width
-# (``member_mean_gap.w8``) and per depth (``member_mean_gap.b16``)
+# cell's limits file names it; ``member_mean_gap`` also comes per group
+# of the family's ``gap_groups`` (``member_mean_gap.b16``)
 GAPS = ("combine_gap", "score_gap", "score_mean_gap", "member_gap",
         "member_mean_gap")
 
 
-def _eq5(col, side_rows, i) -> float:
+def _eq5(col, side) -> float:
     """Eq. 5 as the program's host combine takes it: the float64 mean of
     the member scores, side-model scores appended, as one list."""
-    return float(np.mean(list(col) + [float(r[i]) for r in side_rows]))
+    return float(np.mean(list(col) + list(side)))
+
+
+def _side_scores(cfg, side, cohort, seed, tr):
+    """The plain side models' scores of bed b's close c, in Eq. 5's
+    order, as ``side_of(b, c)``; none without side models."""
+    if not side:
+        return lambda b, c: ()
+    vf = side["vitals_forest"]
+    rvit = _side.VitalsForest(_traffic.N_VITALS, vf["n_trees"],
+                              vf["max_depth"], seed).fit(
+        cohort["vitals"], cohort["label"])
+    lr = side["labs_logistic"]
+    rlab = _side.LogisticRegression(lr["lr"], lr["steps"], lr["l2"],
+                                    seed).fit(cohort["labs"],
+                                              cohort["label"])
+    W = int(cfg["window_s"]) * _traffic.VITALS_HZ
+
+    @functools.lru_cache(maxsize=None)
+    def side_of(b: int, c: int):
+        end = tr.close_vends[b, c]
+        vit = tr.vitals[b, :, end - W: end]
+        return (float(rvit.predict_proba(vit[None])[0]),
+                float(rlab.predict_proba(tr.labs[b, c][None])[0]))
+    return side_of
+
+
+def _served_by(q, cols, side_of):
+    """(close, member column, gap) of the tick that stamped the score
+    the client held for ``q``, or None when no tick stamped this close
+    or a later one of its bed.
+
+    The slot engine answers a close with the bed's newest stamped
+    score, so a close that a later one superseded before any tick
+    stamped it is answered with that later close's score.  The
+    candidates are the bed's closes from ``q``'s own on, in order;
+    the first whose column gives the served score exactly under Eq. 5,
+    with that close's side rows, is the one; failing that, the column
+    that comes nearest."""
+    best = None
+    for v in sorted(v for b, v in cols if b == q.bed and v > q.close):
+        side = side_of(q.bed, v - 1)
+        comb = [abs(q.score - _eq5(c, side)) for c in cols[(q.bed, v)]]
+        k = int(np.argmin(comb))
+        if best is None or comb[k] < best[2]:
+            best = (v - 1, cols[(q.bed, v)][k], comb[k])
+        if comb[k] == 0.0:
+            break
+    return best
 
 
 def _gaps(score, member_mat, combine, ref_score, ref_mat,
-          members) -> Dict:
+          groups: Dict[str, List[int]]) -> Dict:
     """The widest gap of the served score from Eq. 5 over its own
     members, and the widest and the mean gap from the reference of the
     ensemble scores ([windows]) and of the member scores ([members,
-    windows]), the mean also over the members of each width and of each
-    depth."""
+    windows]), the mean also over the members of each group."""
     e = np.abs(score - ref_score)
     d = np.abs(member_mat - ref_mat)
     out = {"combine_gap": float(max(combine)) if len(combine) else None,
@@ -728,11 +803,9 @@ def _gaps(score, member_mat, combine, ref_score, ref_mat,
            "score_mean_gap": float(np.mean(e)),
            "member_gap": float(np.max(d)) if d.size else None,
            "member_mean_gap": float(np.mean(d)) if d.size else None}
-    for key, tag in (("width", "w"), ("blocks", "b")):
-        for v in sorted({getattr(m, key) for m in members}):
-            rows = [i for i, m in enumerate(members) if getattr(m, key) == v]
-            out[f"member_mean_gap.{tag}{v}"] = (
-                float(np.mean(d[rows])) if d.size else None)
+    for tag, rows in groups.items():
+        out[f"member_mean_gap.{tag}"] = (float(np.mean(d[rows])) if d.size
+                                         else None)
     return out
 
 

@@ -1,4 +1,4 @@
-"""Plain reference of the HOLMES ensemble, and the weights it shares
+"""Plain reference of the HOLMES ECG zoo, and the weights it shares
 with the program.
 
 Nothing here imports the program.  It holds:
@@ -20,13 +20,11 @@ Nothing here imports the program.  It holds:
   chip's default, one bfloat16 pass with float32 accumulation), or at
   ``highest`` for a reading beside it; ``dtype=bfloat16`` is the
   lower-precision control, every array and every product in bfloat16;
-* ``member_scores`` / ``ensemble_scores``: P(stable) per member and the
-  bagged mean (Eq. 5), with the side models where the configuration
-  has them;
-* ``RandomForest`` / ``VitalsForest`` / ``LogisticRegression``: copies
-  of the program's numpy side models, fitted here on the same cohort
-  with the same seeds, so the reference takes no fitted table from the
-  program.
+* ``member_scores``: P(stable) per member on given windows, at a
+  precision or in a dtype (``bfloat16``: the control).
+
+Eq. 5 and the side models, which every family is served through, are
+in ``side_reference.py``.
 """
 from __future__ import annotations
 
@@ -253,149 +251,3 @@ def member_scores(params: Sequence[Dict], members: Sequence[Member],
         n = min(block, K - r0)
         out[i, r0:r0 + n] = y[:n]
     return out
-
-
-def ensemble_scores(member_mat: np.ndarray,
-                    side: Sequence[np.ndarray] = ()) -> np.ndarray:
-    """Eq. 5: the mean over members (rows), side-model scores appended
-    as further rows."""
-    rows = [member_mat] + [np.asarray(s, np.float64)[None] for s in side]
-    return np.mean(np.concatenate(rows, axis=0), axis=0)
-
-
-# ------------------------------------------------------- side models
-class DecisionTree:
-    def __init__(self, max_depth: int = 8, min_samples_leaf: int = 2,
-                 max_features: Optional[int] = None, rng=None):
-        self.max_depth = max_depth
-        self.min_samples_leaf = min_samples_leaf
-        self.max_features = max_features
-        self.rng = rng or np.random.default_rng(0)
-        self.nodes: List[list] = []      # [feature, threshold, l, r, value]
-
-    def fit(self, X, y):
-        self.nodes = []
-        self._grow(np.asarray(X, np.float64), np.asarray(y, np.float64), 0)
-        return self
-
-    def _grow(self, X, y, depth) -> int:
-        idx = len(self.nodes)
-        self.nodes.append([-1, 0.0, -1, -1, float(np.mean(y))])
-        n, d = X.shape
-        if depth >= self.max_depth or n < 2 * self.min_samples_leaf \
-                or np.all(y == y[0]):
-            return idx
-        k = self.max_features or max(1, int(np.sqrt(d)))
-        feats = self.rng.choice(d, size=min(k, d), replace=False)
-        best = (0.0, -1, 0.0)
-        total_sum, total_sq = y.sum(), (y ** 2).sum()
-        base = total_sq - total_sum ** 2 / n
-        for f in feats:
-            order = np.argsort(X[:, f], kind="stable")
-            xs, ys = X[order, f], y[order]
-            csum = np.cumsum(ys)[:-1]
-            csq = np.cumsum(ys ** 2)[:-1]
-            nl = np.arange(1, n)
-            valid = xs[1:] != xs[:-1]
-            nl_f = nl.astype(np.float64)
-            sse = ((csq - csum ** 2 / nl_f)
-                   + (total_sq - csq) - (total_sum - csum) ** 2 / (n - nl_f))
-            sse = np.where(valid & (nl >= self.min_samples_leaf)
-                           & (n - nl >= self.min_samples_leaf), sse, np.inf)
-            j = int(np.argmin(sse))
-            gain = base - sse[j]
-            if np.isfinite(sse[j]) and gain > best[0] + 1e-12:
-                best = (gain, f, (xs[j] + xs[j + 1]) / 2.0)
-        if best[1] < 0:
-            return idx
-        _, f, thr = best
-        mask = X[:, f] <= thr
-        self.nodes[idx][0] = f
-        self.nodes[idx][1] = thr
-        self.nodes[idx][2] = self._grow(X[mask], y[mask], depth + 1)
-        self.nodes[idx][3] = self._grow(X[~mask], y[~mask], depth + 1)
-        return idx
-
-    def predict(self, X) -> np.ndarray:
-        X = np.asarray(X, np.float64)
-        out = np.empty(len(X))
-        for i, row in enumerate(X):
-            node = self.nodes[0]
-            while node[0] >= 0:
-                node = self.nodes[node[2] if row[node[0]] <= node[1]
-                                  else node[3]]
-            out[i] = node[4]
-        return out
-
-
-class RandomForest:
-    def __init__(self, n_trees: int, max_depth: int, seed: int,
-                 min_samples_leaf: int = 2):
-        self.n_trees, self.max_depth, self.seed = n_trees, max_depth, seed
-        self.min_samples_leaf = min_samples_leaf
-        self.trees: List[DecisionTree] = []
-
-    def fit(self, X, y):
-        X = np.asarray(X, np.float64)
-        y = np.asarray(y, np.float64)
-        rng = np.random.default_rng(self.seed)
-        n = len(X)
-        self.trees = []
-        for _ in range(self.n_trees):
-            boot = rng.integers(0, n, size=n)
-            self.trees.append(DecisionTree(
-                self.max_depth, self.min_samples_leaf, None, rng
-            ).fit(X[boot], y[boot]))
-        return self
-
-    def predict(self, X) -> np.ndarray:
-        return np.mean([t.predict(X) for t in self.trees], axis=0)
-
-
-class VitalsForest:
-    """One forest per vital sign, their predictions averaged."""
-
-    def __init__(self, n_channels: int, n_trees: int, max_depth: int,
-                 seed: int):
-        self.models = [RandomForest(n_trees, max_depth, seed + i)
-                       for i in range(n_channels)]
-
-    def fit(self, X, y):
-        for c, m in enumerate(self.models):
-            m.fit(X[:, c, :], y)
-        return self
-
-    def predict_proba(self, X) -> np.ndarray:
-        return np.clip(np.mean([m.predict(X[:, c, :])
-                                for c, m in enumerate(self.models)],
-                               axis=0), 0.0, 1.0)
-
-
-class LogisticRegression:
-    def __init__(self, lr: float, steps: int, l2: float, seed: int):
-        self.lr, self.steps, self.l2, self.seed = lr, steps, l2, seed
-
-    def fit(self, X, y):
-        X = np.asarray(X, np.float64)
-        y = np.asarray(y, np.float64)
-        mu, sd = X.mean(0), X.std(0) + 1e-8
-        self._norm = (mu, sd)
-        Xn = (X - mu) / sd
-        rng = np.random.default_rng(self.seed)
-        self.w = rng.normal(0, 0.01, X.shape[1])
-        self.b = 0.0
-        for _ in range(self.steps):
-            p = self._sigmoid(Xn @ self.w + self.b)
-            g = Xn.T @ (p - y) / len(y) + self.l2 * self.w
-            self.w -= self.lr * g
-            self.b -= self.lr * float(np.mean(p - y))
-        return self
-
-    @staticmethod
-    def _sigmoid(z):
-        return 1.0 / (1.0 + np.exp(-np.clip(z, -30, 30)))
-
-    def predict_proba(self, X) -> np.ndarray:
-        mu, sd = self._norm
-        return self._sigmoid(((np.asarray(X, np.float64) - mu) / sd)
-                             @ self.w + self.b)

@@ -4,8 +4,10 @@ correct, the lower-precision control and each fault the cells can have
 are not, and the command refuses to run without a TPU."""
 import json
 import os
+import re
 import subprocess
 import sys
+import time
 
 import numpy as np
 import pytest
@@ -17,19 +19,10 @@ SEED = 2 ** 32 + 17
 WORKLOAD = "zoo60_unit64"
 
 
-# the paper's vitals forests and labs regression beside the ECG zoo, at
-# the program's default sizes
-SIDE_MODELS = {"vitals": 7, "vitals_hz": 1, "labs": 8, "cohort": 96,
-               "vitals_forest": {"n_trees": 25, "max_depth": 6},
-               "labs_logistic": {"lr": 0.1, "steps": 500, "l2": 0.001}}
-
-
-def tiny(config, side=False):
+def tiny(config):
     with open(os.path.join(harness.HERE, "configs", f"{config}.json")) as f:
         cfg = json.load(f)
     cfg.update(widths=[8, 16], blocks=[2, 4], window_s=3)
-    if side:
-        cfg["side_models"] = SIDE_MODELS
     return cfg
 
 
@@ -40,10 +33,10 @@ def tiny_mix(traffic):
 
 
 def run_tiny(workload=WORKLOAD, config="holmes_zoo60", traffic="unit64_hop5",
-             chips=1, side=False, **kw):
+             chips=1, cfg=None, **kw):
     return harness.run(workload, SEED, 2.0, False, platform="cpu",
-                       cfg=tiny(config, side), mix=tiny_mix(traffic),
-                       chips=chips, compare=8, **kw)
+                       cfg=cfg if cfg is not None else tiny(config),
+                       mix=tiny_mix(traffic), chips=chips, compare=8, **kw)
 
 
 def test_tiny_run_is_correct_and_the_control_departs(monkeypatch):
@@ -118,15 +111,79 @@ def _half_the_batch(mp):
     _wrap_bucket_fn(mp, alter)
 
 
-@pytest.mark.parametrize("fault", [_state_unchanged, _half_the_ensemble,
-                                   _half_the_batch, _answer_altered])
+FAULTS = [_state_unchanged, _half_the_ensemble, _half_the_batch,
+          _answer_altered]
+
+
+def _fails_a_gap(out):
+    return out["correct"] is False and any(
+        c["value"] is None or c["value"] > c["limit"]
+        for name, c in out["checks"].items()
+        if name.split(".")[0] in harness.GAPS)
+
+
+@pytest.mark.parametrize("fault", FAULTS)
 def test_fault_is_not_correct(fault, monkeypatch):
     fault(monkeypatch)
-    out = run_tiny()
-    assert out["correct"] is False
-    assert any(c["value"] is None or c["value"] > c["limit"]
-               for name, c in out["checks"].items()
-               if name.split(".")[0] in harness.GAPS)
+    assert _fails_a_gap(run_tiny())
+
+
+@pytest.mark.parametrize("fault", FAULTS)
+def test_fault_is_not_correct_with_side_models(fault, monkeypatch):
+    # the vitals and labs configuration and its stream, on one device
+    # (the exchange is the four-device test's)
+    fault(monkeypatch)
+    assert _fails_a_gap(run_tiny(WORKLOAD, "holmes_zoo60_vitals_labs",
+                                 "stream100_hop2"))
+
+
+def _stalled_run(config, traffic, mp, stall=0.6):
+    # closes every 0.5 s per bed, and one tick held between its snapshot
+    # and its stamp for longer than that, at the first close of the
+    # window: the next close of a bed it scored arrives first, so no
+    # tick stamps the earlier close, and the slot engine answers it with
+    # the newer close's score, well inside the server's 1 s wait
+    from repro.serving.slots import SlotEngine
+    mix = tiny_mix(traffic)
+    mix.update(hop_s=0.5)
+    first = mix["beds"] * (1 + round(mix["preroll_s"] / mix["hop_s"]))
+    orig = SlotEngine._host_combine
+    held = []
+
+    def combine(self, col, extra, vit):
+        if not held and extra.get("qid", -1) >= first:
+            held.append(extra["qid"])
+            time.sleep(stall)
+        return orig(self, col, extra, vit)
+    mp.setattr(SlotEngine, "_host_combine", combine)
+    # every close of the window compared
+    return harness.run(WORKLOAD, SEED, 2.0, False, platform="cpu",
+                       cfg=tiny(config), mix=mix, chips=1, compare=64)
+
+
+SUPERSEDED = re.compile(r"(\d+) of them served with a later close's score")
+
+
+@pytest.mark.parametrize("config,traffic", [
+    ("holmes_zoo60", "unit64_hop5"),
+    ("holmes_zoo60_vitals_labs", "stream100_hop2")])
+def test_a_superseded_close_is_judged_by_the_close_that_served_it(
+        config, traffic, monkeypatch, capsys):
+    out = _stalled_run(config, traffic, monkeypatch)
+    assert int(SUPERSEDED.search(capsys.readouterr().err).group(1)) >= 1
+    assert out["correct"] is True
+    assert out["failed"] == 0
+    assert out["checks"]["members_compared"]["value"] == out["attempted"]
+    assert out["checks"]["combine_gap"]["value"] == 0.0
+
+
+def test_a_superseded_close_served_wrong_is_not_correct(monkeypatch,
+                                                        capsys):
+    _half_the_ensemble(monkeypatch)
+    out = _stalled_run("holmes_zoo60_vitals_labs", "stream100_hop2",
+                       monkeypatch)
+    assert int(SUPERSEDED.search(capsys.readouterr().err).group(1)) >= 1
+    assert _fails_a_gap(out)
 
 
 EXCHANGE = r"""
@@ -143,7 +200,8 @@ def ship(self, packs):
             for k, v in wins.items()}}, n
 if {broken}:
     EnsembleService._ship_packs = ship
-out = t.run_tiny(side=True, chips=4)
+out = t.run_tiny(t.WORKLOAD, "holmes_zoo60_vitals_labs", "stream100_hop2",
+                 chips=4, control=not {broken})
 print(json.dumps(out))
 """
 
@@ -163,6 +221,11 @@ def test_four_chips_and_the_exchange_left_out(broken):
     out = json.loads(p.stdout.strip().splitlines()[-1])
     assert out["device"]["count"] == 4
     assert out["correct"] is (not broken)
+    if not broken:
+        # the side models' rows are in Eq. 5 on both sides, and the
+        # bfloat16 control still departs
+        assert out["checks"]["combine_gap"]["value"] == 0.0
+        assert out["control"]["correct"] is False
 
 
 def test_traced_run_reports_host_metrics():

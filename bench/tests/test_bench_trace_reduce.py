@@ -17,6 +17,11 @@ LOOP = "%fusion.2 = f32[3,64,8] fusion(f32[3,8] %a), kind=kLoop, calls=%f"
 COPY = "%copy.3 = f32[64,3] copy(f32[64,3] %b)"
 
 
+def _conv_s(d):
+    return sum(s for cls, s in d["layer_op_s"].get("bucket", {}).items()
+               if tr.is_conv(cls))
+
+
 def _made():
     return {"devices": {0: {
         "modules": [["jit_fn(1)", 100, 400], ["jit__ingest_padded(2)", 500,
@@ -39,8 +44,13 @@ def test_reduce_hand_made():
     assert d0["busy_s"] == pytest.approx(500e-9)
     assert d0["layer_s"]["bucket"] == pytest.approx(400e-9)
     assert d0["layer_s"]["ingest"] == pytest.approx(100e-9)
-    assert d0["conv_s"] == pytest.approx(250e-9)
-    assert d1["conv_s"] == pytest.approx(100e-9)
+    assert _conv_s(d0) == pytest.approx(250e-9)
+    assert _conv_s(d1) == pytest.approx(100e-9)
+    # every op's time, by layer and op class
+    assert d0["layer_op_s"] == {
+        "bucket": {"fusion/kOutput": pytest.approx(250e-9),
+                   "fusion/kLoop": pytest.approx(160e-9)},
+        "ingest": {"copy": pytest.approx(100e-9)}}
     assert r["program_n"] == {"bucket": 3, "ingest": 1}
     assert r["unmatched_layers"] == []
     gaps = sorted(r["idle_gaps"], key=lambda g: -g[1])
@@ -63,6 +73,8 @@ def test_unmatched_layer_is_reported():
 def test_op_classes():
     assert tr.op_class(CONV) == "fusion/kOutput" and tr.is_conv(CONV)
     assert not tr.is_conv(LOOP) and not tr.is_conv(COPY)
+    # a class is its own class, so a reader can select classes
+    assert tr.is_conv(tr.op_class(CONV)) and not tr.is_conv("fusion/kLoop")
     assert tr.is_conv("%convolution.4 = f32[2] convolution(f32[2] %x)")
 
 
@@ -77,7 +89,10 @@ def test_reduce_recorded_tpu_tick():
     assert r["program_n"]["bucket"] == 2
     assert r["program_n"]["gather"] == 1
     assert r["program_n"]["ingest"] == 64
-    assert 0 < d["conv_s"] < d["layer_s"]["bucket"]
+    assert 0 < _conv_s(d) < d["layer_s"]["bucket"]
+    # op time by layer adds up to the busy time where ops do not overlap
+    assert sum(s for ops in d["layer_op_s"].values()
+               for s in ops.values()) >= d["busy_s"] * 0.999
     # programs span their ops and the short gaps between them
     assert d["busy_s"] <= sum(d["program_s"].values()) < r["window_s"]
     assert len(r["idle_gaps"]) == 10 and len(r["device_ops"]) == 10

@@ -15,9 +15,9 @@ gives, per device:
   names read from ``trace_names/*.json`` (files are merged, so a later
   change can add names without editing one);
 * the number of runs of each layer's programs that started in it;
-* convolution seconds: the ops inside the bucket programs that are
-  convolutions or fusions rooted at one (``kind=kOutput``, which on the
-  TPU is what XLA makes of every convolution here);
+* seconds per layer and op class (``op_class``) of every op, so that a
+  kernel's roofline reader selects its own ops (``layer_op_s``; ops of
+  a program in no layer go under ``other``);
 
 and for the whole trace the top device ops and the longest idle gaps,
 each gap labelled by the host events that overlap it most.
@@ -98,6 +98,9 @@ def op_class(name: str) -> str:
 
 
 def is_conv(name: str) -> bool:
+    """A convolution, or a fusion XLA roots at one (``kind=kOutput``,
+    which on the TPU is what XLA makes of a convolution); ``name`` is an
+    op's name or its ``op_class``."""
     cls = op_class(name)
     return cls.startswith("convolution") or cls.endswith("/kOutput")
 
@@ -133,7 +136,7 @@ def reduce(ex: Dict, layers: Optional[Dict[str, List[str]]] = None,
             if e > s:
                 prog_s[n] = prog_s.get(n, 0.0) + (e - s) * 1e-9
         busy = []
-        conv_ns = 0.0
+        layer_op_s: Dict[str, Dict[str, float]] = {}
         for n, s, e in dev["ops"]:
             cs, ce = _clip(s, e, w0, w1)
             if ce <= cs:
@@ -141,10 +144,11 @@ def reduce(ex: Dict, layers: Optional[Dict[str, List[str]]] = None,
             busy.append((cs, ce))
             i = bisect.bisect_right(starts, s) - 1
             mod = mods[i][2] if i >= 0 and s < mods[i][1] else "?"
-            key = f"{mod} {op_class(n)}"
+            cls = op_class(n)
+            key = f"{mod} {cls}"
             op_time[key] = op_time.get(key, 0.0) + (ce - cs) * 1e-9
-            if to_layer.get(mod) == "bucket" and is_conv(n):
-                conv_ns += ce - cs
+            by_cls = layer_op_s.setdefault(to_layer.get(mod, "other"), {})
+            by_cls[cls] = by_cls.get(cls, 0.0) + (ce - cs) * 1e-9
         busy = _union(busy)
         busy_all += [(dev_id, s, e) for s, e in busy]
         layer_s: Dict[str, float] = {}
@@ -155,7 +159,7 @@ def reduce(ex: Dict, layers: Optional[Dict[str, List[str]]] = None,
         devices[dev_id] = {
             "busy_s": sum(e - s for s, e in busy) * 1e-9,
             "program_s": prog_s, "layer_s": layer_s,
-            "conv_s": conv_ns * 1e-9}
+            "layer_op_s": layer_op_s}
     matched = {layer for d in devices.values() for layer in d["layer_s"]}
     return {
         "window_s": win_ns * 1e-9,
