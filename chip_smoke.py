@@ -218,21 +218,17 @@ def _run_one_chip(members, ingest, refs, clips, *, pallas_impl: str,
 
 def _run_sharded(members, ingest, refs, chips: int, *, log) -> None:
     import jax
-    from repro.configs.ecg_zoo import bucket_zoo
-    from repro.models.ecg_resnext import ecg_macs
     from repro.serving.pipeline import EnsembleService
-    from repro.serving.placement import grouped_lpt_placement
 
     devices = jax.devices()[:chips]
-    groups = list(bucket_zoo([m.spec for m in members]).values())
-    costs = [ecg_macs(members[g[0]].spec) * len(g) for g in groups]
-    plan = grouped_lpt_placement(groups, costs, chips)
+    base = EnsembleService(members)
+    plan = base.plan_placement(chips,
+                               bucket_costs=base.analytic_bucket_costs())
     log(f"placement over {chips} chips (LPT on MACs): members per chip "
         f"{[len(s) for s in plan.assignment]}")
 
     log("one chip:")
-    one, one_flush, _, _ = _serve(EnsembleService(members), ingest, refs,
-                                  log)
+    one, one_flush, _, _ = _serve(base, ingest, refs, log)
     log(f"{chips} chips:")
     sharded_svc = EnsembleService(members, placement=plan,
                                   devices=devices)

@@ -56,9 +56,14 @@ def gqa_apply(p, x: jax.Array, positions: jax.Array, cfg: ArchConfig, *,
               cache_idx: Optional[jax.Array] = None,
               window: int = 0, causal: bool = True,
               kv_mult: int = 1, impl: str = "xla",
-              chunk: int = 0, unroll: bool = False
+              chunk: int = 0, unroll: bool = False,
+              rope: bool = True, scale: Optional[float] = None
               ) -> Tuple[jax.Array, Optional[dict]]:
     """positions: [S] int32 absolute positions of the inputs.
+
+    ``rope=False`` gives NoPE attention (positions then only mask);
+    ``scale`` replaces the 1/sqrt(head_dim) score scale (Granite-4.0-H
+    scales by its ``attention_multiplier``).
 
     * cache=None: full-sequence attention (train/prefill); returns
       (out, {"k","v"}) with M=S so the caller may build a cache.
@@ -66,13 +71,14 @@ def gqa_apply(p, x: jax.Array, positions: jax.Array, cfg: ArchConfig, *,
       whole buffer using cache_pos validity.
     """
     q, k, v = _project_qkv(p, x, cfg, kv_mult)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if rope:
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
 
     if cache is None:
         out = ops.attention(q, k, v, positions, positions,
-                            causal=causal, window=window, impl=impl,
-                            chunk=chunk, unroll=unroll)
+                            causal=causal, window=window, scale=scale,
+                            impl=impl, chunk=chunk, unroll=unroll)
         new_kv = {"k": k, "v": v}
     else:
         M = cache["k"].shape[1]
@@ -82,8 +88,8 @@ def gqa_apply(p, x: jax.Array, positions: jax.Array, cfg: ArchConfig, *,
         kpos = jax.lax.dynamic_update_slice_in_dim(
             cache_pos, positions.astype(cache_pos.dtype), slot, axis=0)
         out = ops.attention(q, ck, cv, positions, kpos,
-                            causal=causal, window=window, impl=impl,
-                            chunk=chunk, unroll=unroll)
+                            causal=causal, window=window, scale=scale,
+                            impl=impl, chunk=chunk, unroll=unroll)
         new_kv = {"k": ck, "v": cv}
     B, S = x.shape[:2]
     out = linear(p["wo"], out.reshape(B, S, cfg.n_heads * cfg.head_dim))

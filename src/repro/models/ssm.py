@@ -73,10 +73,12 @@ def _conv_step(hist: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
 def mamba2_apply(p, u: jax.Array, cfg: ArchConfig, *,
                  cache: Optional[dict] = None,
                  return_cache: bool = False,
-                 impl: str = "xla") -> Tuple[jax.Array, Optional[dict]]:
+                 impl: str = "xla", ssd_impl: Optional[str] = None
+                 ) -> Tuple[jax.Array, Optional[dict]]:
     """u: [B, S, d].  cache given (decode) requires S == 1.
     return_cache=True on the full-sequence path emits the post-prefill
-    conv/ssm state."""
+    conv/ssm state.  ``ssd_impl`` runs the SSD scan on another path than
+    the convolutions (default: ``impl``)."""
     s = cfg.ssm
     B, S, d = u.shape
     di = s.d_inner(d)
@@ -103,7 +105,8 @@ def mamba2_apply(p, u: jax.Array, cfg: ArchConfig, *,
         x = xc.reshape(B, S, H, P)
         Bmat = Bc.reshape(B, S, G, N)
         Cmat = Cc.reshape(B, S, G, N)
-        y, hT = ops.ssd(x, dt, A, Bmat, Cmat, p["D"], s.chunk, impl=impl)
+        y, hT = ops.ssd(x, dt, A, Bmat, Cmat, p["D"], s.chunk,
+                        impl=ssd_impl or impl)
         new_cache = None
         if return_cache:
             new_cache = {"conv_x": x_raw[:, S - (K - 1):, :],

@@ -41,6 +41,20 @@ preserved as ``marshal="legacy"`` — the ingest microbench's baseline
 and a second equivalence oracle.  ``h2d_bytes`` / ``marshal_seconds``
 counters account both regimes for ``BENCH_serving.json["ingest"]``.
 
+Member kinds
+------------
+The service takes members of more than one kind.  Each kind supplies the
+member protocol: ``bucket_key()`` (members with equal keys share one
+stacked program), ``stack(params)`` (the bucket's weights as its program
+takes them), ``bucket_program(group, impl, marshal)`` (a cached jitted
+``(stacked, win [Ppad, 3, L]) -> scores [M, Ppad]`` over the shared window
+pack), ``input_len``, ``cost()`` (MACs per window, for LPT) and
+``tokens`` (sequence positions per window; 0 for a member that is not a
+sequence model).  ``ZooMember`` (a stacked 1-D ResNeXt, programs named
+``fn``) and ``HybridMember`` (a Granite-4.0-H Mamba-2 + GQA stack,
+program ``hybrid_member``) implement it; the bucket plan, warm-up and
+placement read only the protocol.
+
 Multi-device sharded serving (``placement=``)
 ---------------------------------------------
 A ``serving.placement.Placement`` shards the stacked bucket params
@@ -60,17 +74,19 @@ import dataclasses
 import functools
 import threading
 import time
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, Hashable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.ecg_zoo import (CLIP_SECONDS, ECG_HZ, ECG_LEADS,
-                                   EcgModelSpec, VITALS_HZ, bucket_zoo)
+                                   EcgModelSpec, VITALS_HZ, bucket_key)
+from repro.configs.granite_4h import GraniteHybridSpec
 from repro.obs import spans as _spans
 from repro.launch.ensemble_parallel import stack_members
-from repro.models.ecg_resnext import ecg_apply, ecg_apply_stacked
+from repro.models.ecg_resnext import ecg_apply, ecg_apply_stacked, ecg_macs
+from repro.models.granite_hybrid import granite_apply_stacked, granite_macs
 from repro.serving.aggregator import (DeviceIngest, DeviceWindowRef,
                                       ModalitySpec, PatientAggregator,
                                       gather_windows, pow2_rung)
@@ -80,8 +96,88 @@ from repro.serving.placement import (Placement, grouped_lpt_placement,
 
 @dataclasses.dataclass
 class ZooMember:
+    """A 1-D stripe ResNeXt of the paper's zoo: one lead, stacked with
+    the members of its ``bucket_key`` into one program named ``fn``."""
     spec: EcgModelSpec
     params: Dict
+    kind = "resnext"
+    tokens = 0
+
+    @property
+    def input_len(self) -> int:
+        return self.spec.input_len
+
+    def bucket_key(self) -> Hashable:
+        return bucket_key(self.spec)
+
+    def cost(self) -> float:
+        return ecg_macs(self.spec)
+
+    @staticmethod
+    def stack(params: Sequence[Dict]) -> Dict:
+        return stack_members(list(params))
+
+    def bucket_program(self, group: Sequence["ZooMember"], impl: str,
+                       marshal: str) -> Callable:
+        return _make_bucket_fn(self.spec, [m.spec.lead for m in group],
+                               impl, marshal)
+
+
+@dataclasses.dataclass
+class HybridMember:
+    """A Granite-4.0-H hybrid (Mamba-2 + NoPE GQA) over all three leads'
+    0.1 s patches, scored by its last token; its program is named
+    ``hybrid_member``.  The SSD scan runs in the Pallas kernel on a TPU
+    and in its jnp form elsewhere."""
+    spec: GraniteHybridSpec
+    params: Dict
+    kind = "hybrid"
+
+    @property
+    def input_len(self) -> int:
+        return self.spec.input_len
+
+    @property
+    def tokens(self) -> int:
+        return self.spec.tokens
+
+    @staticmethod
+    def _ssd() -> str:
+        return "pallas" if jax.default_backend() == "tpu" else "xla"
+
+    def bucket_key(self) -> Hashable:
+        return ("hybrid", self.spec, self._ssd())
+
+    def cost(self) -> float:
+        return granite_macs(self.spec)
+
+    @staticmethod
+    def stack(params: Sequence[Dict]) -> Tuple[Dict, ...]:
+        # held as they are: stacking gigabytes of weights would copy them
+        return tuple(params)
+
+    def bucket_program(self, group: Sequence["HybridMember"], impl: str,
+                       marshal: str) -> Callable:
+        if marshal != "packed":
+            raise ValueError(f"a {self.kind} member needs the packed "
+                             f"marshal, not {marshal!r}")
+        return _make_hybrid_fn(self.spec, self._ssd())
+
+
+def bucket_members(members: Sequence) -> Dict[Hashable, List[int]]:
+    """Member indices grouped by ``bucket_key()``, insertion-ordered
+    (for ResNeXt members exactly ``configs.ecg_zoo.bucket_zoo``)."""
+    out: Dict[Hashable, List[int]] = {}
+    for i, m in enumerate(members):
+        out.setdefault(m.bucket_key(), []).append(i)
+    return out
+
+
+def _resnext_only(members: Sequence, what: str) -> None:
+    kinds = sorted({m.kind for m in members} - {"resnext"})
+    if kinds:
+        raise ValueError(f"{what} runs ResNeXt programs only; {kinds} "
+                         f"members serve on the fused packed path")
 
 
 @dataclasses.dataclass
@@ -89,13 +185,15 @@ class _Bucket:
     """One stacked-execution group: structurally identical members.
     With a placement this is a (bucket, device) SHARD — the same bucket
     may appear once per device its members were assigned to."""
-    spec: EcgModelSpec            # shape-defining representative
+    spec: object                  # shape-defining representative's spec
     idx: List[int]                # member indices into self.members
     leads: List[int]              # per stacked member, the lead it reads
-    stacked: Dict                 # stack_members() pytree, leading axis M
-    fn: Callable                  # jitted [M, P, L, 1] -> scores [M, P]
+    stacked: Dict                 # the kind's stack() of the weights
+    fn: Callable                  # jitted (stacked, win) -> scores [M, P]
     device: object = None         # jax.Device the shard is pinned to
     slot: int = 0                 # placement slot index (0 if unsharded)
+    kind: str = "resnext"         # the members' kind
+    tokens: int = 0               # sequence positions per window
 
 
 def _make_member_fn(params: Dict, spec: EcgModelSpec,
@@ -125,6 +223,10 @@ def _make_bucket_fn_legacy_cached(spec: EcgModelSpec,
     """Pre-refactor dispatch: takes the host-marshaled [M, Ppad, L, 1]
     member-expanded input (``marshal="legacy"``) — kept as the ingest
     microbench baseline and equivalence oracle."""
+    if not isinstance(spec, EcgModelSpec):
+        raise ValueError(f"the legacy marshal runs ResNeXt programs only, "
+                         f"not {type(spec).__name__}")
+
     @jax.jit
     def fn(stacked: Dict, xs: jax.Array) -> jax.Array:
         logits = ecg_apply_stacked(stacked, xs, spec, impl=impl)
@@ -146,6 +248,18 @@ def _make_bucket_fn(spec: EcgModelSpec, leads: Sequence[int],
     if marshal == "legacy":
         return _make_bucket_fn_legacy_cached(blank, impl)
     return _make_bucket_fn_cached(blank, tuple(leads), impl)
+
+
+@functools.lru_cache(maxsize=None)
+def _make_hybrid_fn(spec: GraniteHybridSpec, ssd_impl: str) -> Callable:
+    """One jit object per hybrid shape, named ``hybrid_member`` so the
+    device trace shows it as ``jit_hybrid_member``, apart from the zoo's
+    ``jit_fn``."""
+    def hybrid_member(stacked, win: jax.Array) -> jax.Array:
+        logits = granite_apply_stacked(stacked, win, spec,
+                                       ssd_impl=ssd_impl)
+        return jax.nn.softmax(logits, axis=-1)[..., 1]     # [M, P]
+    return jax.jit(hybrid_member)
 
 
 # flush-size ladder: micro-batches pad up to aggregator.pow2_rung so
@@ -243,8 +357,11 @@ class EnsembleService:
         self.retire_alpha = RETIRE_ALPHA
         self._shard_ewma: Dict[Tuple[int, ...], float] = {}
         self._count_lock = threading.Lock()    # server workers share us
-        self._fns: List[Callable] = [
-            _make_member_fn(m.params, m.spec, impl) for m in self.members]
+        if not fused:
+            _resnext_only(self.members, "fused=False")
+        self._fns: List[Optional[Callable]] = [
+            _make_member_fn(m.params, m.spec, impl)
+            if m.kind == "resnext" else None for m in self.members]
         self._bucket_cache: Optional[List[_Bucket]] = None
 
     @classmethod
@@ -267,9 +384,9 @@ class EnsembleService:
         return self._bucket_cache
 
     def _build_buckets(self) -> List[_Bucket]:
-        specs = [m.spec for m in self.members]
+        members = self.members
         if self.placement is None:
-            groups = [(0, None, list(range(len(specs))))]
+            groups = [(0, None, list(range(len(members))))]
         else:
             devs = self._devices if self._devices is not None \
                 else jax.devices()
@@ -287,21 +404,21 @@ class EnsembleService:
                       if slot]
         out = []
         for slot_idx, dev, mem_idx in groups:
-            for local in bucket_zoo([specs[i] for i in mem_idx]).values():
+            local_members = [members[i] for i in mem_idx]
+            for local in bucket_members(local_members).values():
                 idx = [mem_idx[j] for j in local]
-                spec = specs[idx[0]]
-                stacked = stack_members([self.members[i].params
-                                         for i in idx])
+                group = [members[i] for i in idx]
+                rep = group[0]
+                stacked = rep.stack([m.params for m in group])
                 if dev is not None:
                     stacked = jax.device_put(stacked, dev)
-                leads = [specs[i].lead for i in idx]
                 out.append(_Bucket(
-                    spec=spec, idx=idx,
-                    leads=leads,
+                    spec=rep.spec, idx=idx,
+                    leads=[getattr(m.spec, "lead", None) for m in group],
                     stacked=stacked,
-                    fn=_make_bucket_fn(spec, leads, self.impl,
-                                       self.marshal),
-                    device=dev, slot=slot_idx))
+                    fn=rep.bucket_program(group, self.impl, self.marshal),
+                    device=dev, slot=slot_idx, kind=rep.kind,
+                    tokens=rep.tokens))
         return out
 
     @property
@@ -326,7 +443,7 @@ class EnsembleService:
         ladder, and per-bucket cost ratios at batch 1 differ from the
         ratios the plan will actually see.  ``speeds`` (one per slot)
         makes the plan heterogeneity-aware — see ``lpt_placement``."""
-        groups = list(bucket_zoo([m.spec for m in self.members]).values())
+        groups = list(bucket_members(self.members).values())
         if bucket_costs is None:
             if self.placement is not None:
                 raise ValueError("measure bucket costs on an unsharded "
@@ -335,6 +452,13 @@ class EnsembleService:
                 reps=reps, batch=PLAN_BATCH if batch is None else batch)
         return grouped_lpt_placement(groups, list(bucket_costs),
                                      n_devices, speeds=speeds)
+
+    def analytic_bucket_costs(self) -> List[float]:
+        """Per-bucket LPT costs from the members' own ``cost()`` (MACs
+        per window), ordered like ``plan_placement``'s groups: a plan
+        that needs no timing pass."""
+        return [sum(self.members[i].cost() for i in g)
+                for g in bucket_members(self.members).values()]
 
     # ---------------------------------------------------------- warmup
     def _bucket_input(self, b: _Bucket, p: int) -> jax.Array:
@@ -374,6 +498,7 @@ class EnsembleService:
         needs individual member costs regardless of fused serving.
         ``warmup`` untimed calls precede the timed reps so compile time
         never leaks into the estimate."""
+        _resnext_only(self.members, "measured_costs")
         out = []
         for m, fn in zip(self.members, self._fns):
             x = jnp.zeros((1, m.spec.input_len, 1))
@@ -531,7 +656,7 @@ class EnsembleService:
         snap = self.shard_cost_snapshot()
         if not snap:
             return None
-        groups = list(bucket_zoo([m.spec for m in self.members]).values())
+        groups = list(bucket_members(self.members).values())
         speed_of = {}
         if self._bucket_cache is not None:
             sp = self.placement.speeds if self.placement is not None \
@@ -684,6 +809,7 @@ class EnsembleService:
         is marshaled by a host (member, patient) double loop and
         shipped whole — M x L floats per patient per bucket.  Kept
         behind ``marshal="legacy"`` as the ingest bench baseline."""
+        _resnext_only(self.members, 'marshal="legacy"')
         P = len(batch)
         Ppad = _next_pow2(P)
         score_mat = np.zeros((len(self.members), P))
@@ -724,6 +850,7 @@ class EnsembleService:
 
     def _predict_one_unfused(self, windows: Dict[str, np.ndarray]
                              ) -> float:
+        _resnext_only(self.members, "the unfused per-member path")
         ecg = windows.get("ecg")
         if self.dispatch_guard is not None:
             self.dispatch_guard(None)       # unfused runs on the default

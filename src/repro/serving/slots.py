@@ -153,7 +153,13 @@ class TickReport:
     one after another.  On a tick that scored a slot ``seconds`` ends
     where the stamp begins, so every phase but the stamp lies inside
     it.  A tick whose gather committed staged ingest packets also has
-    ``ingest.commit``, which lies inside ``slots.tick.gather``."""
+    ``ingest.commit``, which lies inside ``slots.tick.gather``; one that
+    dispatched a sequence member's program (``HybridMember``) has
+    ``slots.tick.dispatch.hybrid``, inside ``slots.tick.dispatch``.
+
+    ``hybrid_tokens`` counts the sequence positions the hybrid members
+    scored: the slots scored times each such member's tokens per
+    window (the padding rows of the rung are not counted)."""
     tick: int                       # tick ordinal after this tick
     n_scored: int                   # occupied slots scored this tick
     n_stale: int                    # occupied slots skipped (ring overrun)
@@ -165,6 +171,7 @@ class TickReport:
     spad: int = 0                   # pad rung (oracle batch size)
     skipped: bool = False           # tick-lock timeout: nothing ran
     phases: Dict[str, float] = dataclasses.field(default_factory=dict)
+    hybrid_tokens: int = 0          # tokens scored by hybrid members
 
 
 class SlotEngine:
@@ -609,13 +616,19 @@ class SlotEngine:
         with _spans.phase("slots.tick.dispatch"):
             group_cands: List[Tuple[jax.Array, ...]] = []
             n_disp = 0
+            tokens = 0
             for g in self.groups:
                 cands = []
                 for b in g.buckets:
                     if guard is not None:
                         guard(b.device)
-                    cands.append(b.fn(
-                        b.stacked, dev_wins[(b.spec.input_len, b.device)]))
+                    win = dev_wins[(b.spec.input_len, b.device)]
+                    if b.tokens:
+                        with _spans.phase(f"slots.tick.dispatch.{b.kind}"):
+                            cands.append(b.fn(b.stacked, win))
+                        tokens += b.tokens * len(b.idx) * len(scored)
+                    else:
+                        cands.append(b.fn(b.stacked, win))
                 n_disp += len(g.buckets)
                 group_cands.append(tuple(cands))
 
@@ -681,7 +694,8 @@ class SlotEngine:
         return TickReport(
             tick, len(scored), int(stale.sum()), wall,
             scored, stamped=st_ids, versions=versions[st_ids].copy(),
-            scores=np.asarray([fresh[int(s)] for s in st_ids]), spad=spad)
+            scores=np.asarray([fresh[int(s)] for s in st_ids]), spad=spad,
+            hybrid_tokens=tokens)
 
     def _host_combine(self, score_col: np.ndarray, extra: Dict,
                       vit_row: Optional[np.ndarray]) -> float:

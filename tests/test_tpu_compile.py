@@ -16,6 +16,7 @@ import pytest
 
 from repro.configs.ecg_zoo import bucket_zoo, zoo_specs
 from repro.kernels.conv1d_stripe import conv1d_stripe_stacked
+from repro.kernels.ssd_scan import ssd
 from repro.kernels.window_gather import window_gather
 from repro.launch.ensemble_parallel import stack_members
 from repro.models.ecg_resnext import init_ecg
@@ -103,4 +104,18 @@ def test_xla_bucket_program_compiles(one_chip):
     fn = _make_bucket_fn(spec, [specs[i].lead for i in idx], "xla")
     compiled = fn.lower(
         stacked, _shape(one_chip, (64, 3, spec.input_len))).compile()
+    _fits(compiled)
+
+
+def test_ssd_scan_compiles_at_the_hybrid_members_widths(one_chip):
+    """The Pallas SSD scan of the Granite-4.0-H member at its published
+    widths (64 heads of 64, d_state 128, one group, chunk 256) over a
+    64-bed tick of 300 tokens: the last chunk is partial."""
+    b, S, H, P, N = 64, 300, 64, 64, 128
+    compiled = jax.jit(lambda x, dt, A, B_, C, D: ssd(
+        x, dt, A, B_, C, D, 256)).lower(
+        _shape(one_chip, (b, S, H, P)), _shape(one_chip, (b, S, H)),
+        _shape(one_chip, (H,)), _shape(one_chip, (b, S, 1, N)),
+        _shape(one_chip, (b, S, 1, N)), _shape(one_chip, (H,))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
     _fits(compiled)
