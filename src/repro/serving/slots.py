@@ -46,6 +46,17 @@ One caveat inherited from XLA: a flush of exactly ONE window compiles
 a different (batch-1-specialized) program, so the oracle comparison
 holds for flushes of two or more windows.
 
+Side-model scores are a property of the close, not of the tick.  The
+vitals window a slot's combine reads ends where its close put it and
+the labs vector is the close's own, so the first tick to combine a
+close runs the CPU-side models once and keeps the two floats on the
+close record (``_Close``); every later tick that combines the same
+close appends those floats instead of recomputing them, so the
+combined score stays bitwise the per-tick recomputation.  A new close,
+a discharge or readmission (a new record), census growth and a rebind
+(``_side_gen``) all start fresh; a stale slot is masked out of the
+tick before its record is consulted.
+
 Staleness is a tick-age guard: a slot whose ring data was overwritten
 before the tick could gather it (the same two-host-int check the
 flush uses) is skipped — its mirror keeps the last good score and its
@@ -128,6 +139,13 @@ def _fleet_mean(mats: Tuple[jax.Array, ...]) -> jax.Array:
     return jnp.mean(jnp.concatenate(mats, axis=0), axis=0)
 
 
+class _Close(dict):
+    """A slot's close record: the ref's ``extra``, copied by
+    ``update()``, plus ``side``, the first combine's side-model scores
+    for this close as ``(side generation, [float, ...])``."""
+    side: Optional[Tuple[int, List[float]]] = None
+
+
 @dataclasses.dataclass
 class _Group:
     """Per-device slice of the tick: the bucket shards pinned to one
@@ -159,7 +177,12 @@ class TickReport:
 
     ``hybrid_tokens`` counts the sequence positions the hybrid members
     scored: the slots scored times each such member's tokens per
-    window (the padding rows of the rung are not counted)."""
+    window (the padding rows of the rung are not counted).
+
+    ``side_calls`` counts the scored slots whose side models (vitals,
+    labs) ran in this tick's combine, ``side_reused`` those whose side
+    scores came from their close's memo; with side models they add up
+    to ``n_scored``, without them both are 0."""
     tick: int                       # tick ordinal after this tick
     n_scored: int                   # occupied slots scored this tick
     n_stale: int                    # occupied slots skipped (ring overrun)
@@ -172,6 +195,8 @@ class TickReport:
     skipped: bool = False           # tick-lock timeout: nothing ran
     phases: Dict[str, float] = dataclasses.field(default_factory=dict)
     hybrid_tokens: int = 0          # tokens scored by hybrid members
+    side_calls: int = 0             # slots whose side models ran
+    side_reused: int = 0            # slots whose side scores were kept
 
 
 class SlotEngine:
@@ -258,6 +283,9 @@ class SlotEngine:
         self.tick_count = 0
         # counters (bench surface)
         self.dispatch_count = 0      # stacked bucket dispatches by ticks
+        self.side_calls = 0          # combines that ran the side models
+        self.side_reused = 0         # combines that kept a close's scores
+        self._side_gen = 0           # bumped by growth and rebind
         self.n_admits = 0
         self.n_discharges = 0
         self.n_tick_faults = 0       # DeviceLostError raised inside a tick
@@ -312,6 +340,7 @@ class SlotEngine:
                                        for b in service._buckets}))
             self.groups = self._build_groups(service)
             self.device_scores = None
+            self._side_gen += 1
             with self._lock:
                 self.n_rebinds += 1
 
@@ -394,6 +423,7 @@ class SlotEngine:
                 self.n_slots = new_n
                 self._Spad = new_spad
                 self.n_grows += 1
+            self._side_gen += 1
             self._pj = jnp.asarray(
                 np.pad(np.arange(self.n_slots, dtype=np.int32),
                        (0, self._Spad - self.n_slots)))
@@ -449,7 +479,7 @@ class SlotEngine:
             for m in ref.ends:
                 self._ends[m][s] = ref.ends[m]
                 self._valid[m][s] = ref.valid[m]
-            self._extra[s] = dict(ref.extra)
+            self._extra[s] = _Close(ref.extra)
             self.has_window[s] = True
             self._close_version[s] += 1
             return int(self._close_version[s])
@@ -655,11 +685,14 @@ class SlotEngine:
                 score_mat[g.rows] = np.asarray(
                     jax.block_until_ready(g.state))
         with _spans.phase("slots.tick.combine"):
+            calls0, reused0 = self.side_calls, self.side_reused
             fresh: Dict[int, float] = {}
             for s in scored:
                 fresh[int(s)] = self._host_combine(
                     score_mat[:, s], extras[s],
                     vit_rows[s] if vit_rows is not None else None)
+            side_calls = self.side_calls - calls0
+            side_reused = self.side_reused - reused0
 
         hook = self._pre_stamp_hook
         if hook is not None:
@@ -695,26 +728,46 @@ class SlotEngine:
             tick, len(scored), int(stale.sum()), wall,
             scored, stamped=st_ids, versions=versions[st_ids].copy(),
             scores=np.asarray([fresh[int(s)] for s in st_ids]), spad=spad,
-            hybrid_tokens=tokens)
+            hybrid_tokens=tokens, side_calls=side_calls,
+            side_reused=side_reused)
 
     def _host_combine(self, score_col: np.ndarray, extra: Dict,
                       vit_row: Optional[np.ndarray]) -> float:
         """``EnsembleService._combine`` for one slot, verbatim: python
         list of float64 member scores, CPU-side models appended in the
-        same order, ``np.mean`` over the list."""
+        same order, ``np.mean`` over the list.  The CPU-side scores
+        come from ``_side_scores``: run once per close, then kept."""
         svc = self.service
         scores = list(score_col) if len(svc.members) else []
+        if svc.vitals_model is not None or svc.labs_model is not None:
+            scores += self._side_scores(extra, vit_row)
+        return float(np.mean(scores)) if scores else 0.5
+
+    def _side_scores(self, extra: _Close,
+                     vit_row: Optional[np.ndarray]) -> List[float]:
+        """The vitals and labs scores of the close ``extra`` records, in
+        ``_combine``'s order: the floats an earlier combine of this
+        close kept on its record when the side generation still
+        matches, else one ``predict_proba`` row per model, kept."""
+        memo = extra.side
+        if memo is not None and memo[0] == self._side_gen:
+            self.side_reused += 1
+            return memo[1]
+        svc = self.service
+        side: List[float] = []
         if svc.vitals_model is not None:
             vit = vit_row if vit_row is not None else extra.get("vitals")
             if vit is not None:
-                scores.append(float(
+                side.append(float(
                     svc.vitals_model.predict_proba(vit[None])[0]))
         if svc.labs_model is not None:
             labs = extra.get("labs")
             if labs is not None:
-                scores.append(float(
+                side.append(float(
                     svc.labs_model.predict_proba(labs[None])[0]))
-        return float(np.mean(scores)) if scores else 0.5
+        extra.side = (self._side_gen, side)
+        self.side_calls += 1
+        return side
 
     # ------------------------------------------------------------ reads
     def read(self, slot: int,

@@ -409,7 +409,16 @@ def test_churn_hammer_never_wrong(zoo_members, rng):
                threading.Thread(target=churner, daemon=True)]
     for th in threads:
         th.start()
+    # hammer for 2 s, and on until a tick has stamped: the first tick
+    # at the grown rung compiles for seconds on a CPU, and before it
+    # the churner leaves few slots alive through a whole tick
     time.sleep(2.0)
+    deadline = time.monotonic() + 60.0
+    while time.monotonic() < deadline:
+        with lock:
+            if rec:
+                break
+        time.sleep(0.05)
     stop.set()
     for th in threads:
         th.join(timeout=5.0)

@@ -177,6 +177,165 @@ def test_tick_bitwise_with_cpu_side_models(zoo_members, rng):
                           _oracle(svc, refs, [0, 1]))
 
 
+class _CountingModel:
+    """A side model that counts the rows it scores and answers a float
+    that depends on every input value (``bias`` tells two apart)."""
+
+    def __init__(self, bias=0.0):
+        self.bias = bias
+        self.rows = 0
+
+    def predict_proba(self, x):
+        x = np.asarray(x, np.float64)
+        self.rows += len(x)
+        z = x.reshape(len(x), -1).sum(axis=1) + self.bias
+        return 1.0 / (1.0 + np.exp(-z))
+
+
+def _side_round(di, rng, patients, t):
+    """One vitals sample per patient, then a closed window whose labs
+    vector is new too: every side-model input of the close is fresh."""
+    for p in patients:
+        di.ingest(t, p, "vitals",
+                  rng.standard_normal((7, 1)).astype(np.float32))
+    labs = rng.standard_normal(8).astype(np.float32)
+    return _close_round(di, rng, patients, t0=t, extra={"labs": labs})
+
+
+def _side_engine(zoo_members, rng, n, bias=0.0):
+    models = (_CountingModel(bias), _CountingModel(bias - 1.0))
+    svc = EnsembleService(zoo_members, vitals_model=models[0],
+                          labs_model=models[1])
+    di = _make_ingest(n, vitals=True)
+    eng = SlotEngine(svc, di)
+    refs = _side_round(di, rng, range(n), 0.0)
+    for p in range(n):
+        eng.update(refs[p])
+    return svc, di, eng, models, refs
+
+
+def _side_rows(models):
+    return tuple(m.rows for m in models)
+
+
+def test_side_models_run_once_per_close(zoo_members, rng):
+    """Over ticks in which only some beds close a new window, each side
+    model scores one row per (slot, close) and no more; every tick's
+    reads stay bitwise the flush oracle over the current refs, and the
+    report's two counters split the scored slots."""
+    n = 4
+    svc, di, eng, models, refs = _side_engine(zoo_members, rng, n)
+    patients = list(range(n))
+    closes = n
+    for t, closing in enumerate([[], [], [1, 3], [0], []], start=1):
+        if closing:
+            new = _side_round(di, rng, closing, float(t))
+            for p in closing:
+                eng.update(new[p])
+            refs.update(new)
+            closes += len(closing)
+        rep = eng.tick()
+        fresh = n if t == 1 else len(closing)
+        assert (rep.side_calls, rep.side_reused) == (fresh, n - fresh)
+        assert rep.side_calls + rep.side_reused == rep.n_scored == n
+        assert _side_rows(models) == (closes, closes)
+        assert np.array_equal(_reads(eng, patients),
+                              _oracle(svc, refs, patients)), f"tick {t}"
+        for m in models:                 # the oracle's own rows
+            m.rows = closes
+    assert (eng.side_calls, eng.side_reused) == (closes, 5 * n - closes)
+
+
+def _event_new_close(eng, di, rng, refs, zoo_members):
+    refs.update(_side_round(di, rng, [0], 1.0))
+    eng.update(refs[0])
+    return eng.service, [0]
+
+
+def _event_readmit(eng, di, rng, refs, zoo_members):
+    eng.discharge(0)
+    refs.update(_side_round(di, rng, [0], 1.0))
+    eng.update(refs[0])
+    return eng.service, [0]
+
+
+def _event_ensure_slots(eng, di, rng, refs, zoo_members):
+    eng.ensure_slots(eng.n_slots + 1)
+    return eng.service, list(refs)
+
+
+def _event_rebind(eng, di, rng, refs, zoo_members):
+    svc2 = EnsembleService(zoo_members, vitals_model=_CountingModel(0.5),
+                           labs_model=_CountingModel(-0.5))
+    eng.rebind(svc2)
+    return svc2, list(refs)
+
+
+def _event_stale_then_close(eng, di, rng, refs, zoo_members):
+    # slot 0's close scrolls out of its ECG ring: masked out of the
+    # next tick, whose combine never consults its record
+    for w in range(1, 4):
+        for off in range(0, 250, 50):
+            di.ingest(w + off / 250.0, 0, "ecg",
+                      rng.standard_normal((3, 50)).astype(np.float32))
+    before = _side_rows((eng.service.vitals_model,
+                         eng.service.labs_model))
+    rep = eng.tick()
+    assert rep.n_stale == 1
+    assert (rep.side_calls, rep.side_reused) == (0, len(refs) - 1)
+    assert _side_rows((eng.service.vitals_model,
+                       eng.service.labs_model)) == before
+    refs.update(_side_round(di, rng, [0], 4.0))
+    eng.update(refs[0])
+    return eng.service, [0]
+
+
+@pytest.mark.parametrize("event", [
+    _event_new_close, _event_readmit, _event_ensure_slots,
+    _event_rebind, _event_stale_then_close])
+def test_side_scores_recomputed_when_the_close_changes(event, zoo_members,
+                                                       rng):
+    """A new close, discharge then readmission, census growth, a rebind
+    to other side models, and a close after a stale ring each run the
+    side models again for the slots they touch, and the reads equal
+    the flush oracle over the current refs and the current service."""
+    n = 4
+    _, di, eng, _, refs = _side_engine(zoo_members, rng, n)
+    eng.tick()
+    rep = eng.tick()
+    assert (rep.side_calls, rep.side_reused) == (0, n)
+    svc, redo = event(eng, di, rng, refs, zoo_members)
+    models = (svc.vitals_model, svc.labs_model)
+    before = _side_rows(models)
+    rep = eng.tick()
+    assert (rep.side_calls, rep.side_reused) == (len(redo), n - len(redo))
+    assert _side_rows(models) == tuple(b + len(redo) for b in before)
+    # the oracle at the engine's pad rung (growth doubles it)
+    patients = list(range(n))
+    rung = pow2_rung(eng.n_slots) // n
+    want = _oracle(svc, refs, patients * rung)[:n]
+    assert np.array_equal(_reads(eng, patients), want)
+
+
+def test_no_side_models_no_side_counts(zoo_members, rng):
+    """A service without side models combines exactly as before: both
+    counters stay 0 and the reads are the flush oracle's."""
+    svc = EnsembleService(zoo_members)
+    di = _make_ingest(4)
+    eng = SlotEngine(svc, di)
+    patients = list(range(4))
+    refs = _close_round(di, rng, patients, t0=0.0)
+    for p in patients:
+        eng.update(refs[p])
+    for _ in range(2):
+        rep = eng.tick()
+        assert rep.n_scored == 4
+        assert (rep.side_calls, rep.side_reused) == (0, 0)
+        assert np.array_equal(_reads(eng, patients),
+                              _oracle(svc, refs, patients))
+    assert (eng.side_calls, eng.side_reused) == (0, 0)
+
+
 def test_occupancy_churn_discharge_and_readmit(zoo_members, rng):
     """Slot insert/free mid-serving: a discharged slot's read raises
     and its score never leaks into survivors; re-admission serves the
