@@ -77,7 +77,8 @@ def main(argv=None) -> None:
           lambda: serving_bench.bench_fused_serving(
               reps=3 if args.quick else 10,
               write_json=not args.quick),
-          lambda t: f"speedup={t['speedup_fused_microbatch']:.2f}x")
+          lambda t: "microbatch_ms_per_query="
+          + f"{t['modes']['fused_microbatch']['per_query_ms']:.2f}")
     # multi-device placement sweep: degrades to whatever device count
     # this process was launched with (run serving_bench.py standalone,
     # or under the CI lane's XLA_FLAGS, for the full 8-device sweep)

@@ -1,7 +1,7 @@
 """Fig. 9 / Fig. 10 / Fig. 13: serving-system benchmarks on the DES
 (deterministic stand-in for the paper's HTTP/RPC testbed) plus real
 wall-clock jitted-inference costs measured on this machine, the
-fused-serving before/after microbench (``bench_fused_serving``), and
+bucketed-serving microbench (``bench_fused_serving``), and
 the multi-device placement sweep (``bench_placement_sweep``) — both
 tracked in ``BENCH_serving.json``.
 
@@ -126,11 +126,9 @@ def bench_fig13(windows=(5, 10, 30, 60), model_cost_per_s: float = 7e-4,
 def bench_fused_serving(n_patients: int = 16, reps: int = 10,
                         input_len: int = 750, verbose=True,
                         write_json: bool = True) -> Dict:
-    """Before/after microbench of the fused serving hot path on the
-    reduced 12-member zoo x ``n_patients`` streaming patients:
+    """Microbench of the bucketed serving hot path on the reduced
+    12-member zoo x ``n_patients`` streaming patients:
 
-    * ``per_member``       — the old loop: one jitted dispatch + sync
-                             per member per patient (12/query);
     * ``fused``            — architecture-bucketed stacked execution,
                              one dispatch per bucket (4/query);
     * ``fused_microbatch`` — fused + cross-patient micro-batching: one
@@ -152,14 +150,12 @@ def bench_fused_serving(n_patients: int = 16, reps: int = 10,
     windows = [{"ecg": rng.standard_normal((3, input_len))
                 .astype(np.float32)} for _ in range(n_patients)]
 
-    modes = (("per_member", False, False), ("fused", True, False),
-             ("fused_microbatch", True, True))
+    modes = (("fused", False), ("fused_microbatch", True))
     out: Dict = {"n_patients": n_patients, "n_members": len(members),
                  "reps": reps, "input_len": input_len, "modes": {}}
-    for name, fused, microbatch in modes:
-        svc = EnsembleService(members, fused=fused)
-        if fused:
-            out["n_buckets"] = svc.n_buckets
+    for name, microbatch in modes:
+        svc = EnsembleService(members)
+        out["n_buckets"] = svc.n_buckets
         if microbatch:
             svc.predict_batch(windows)                 # warmup/compile
         else:
@@ -179,10 +175,6 @@ def bench_fused_serving(n_patients: int = 16, reps: int = 10,
             "sustained_qps": n_q / dt,
             "dispatches_per_query": (svc.dispatch_count - d0) / n_q,
         }
-    base = out["modes"]["per_member"]
-    best = out["modes"]["fused_microbatch"]
-    out["speedup_fused_microbatch"] = (base["per_query_ms"]
-                                       / best["per_query_ms"])
     if verbose:
         print(f"\nfused serving bench (reduced zoo x {n_patients} "
               f"patients, CPU):")
@@ -190,8 +182,6 @@ def bench_fused_serving(n_patients: int = 16, reps: int = 10,
             print(f"  {name:17s}: {m['per_query_ms']:7.2f} ms/query  "
                   f"{m['sustained_qps']:7.1f} q/s  "
                   f"{m['dispatches_per_query']:5.2f} dispatches/query")
-        print(f"  speedup (fused+microbatch vs per-member): "
-              f"{out['speedup_fused_microbatch']:.2f}x")
     if write_json:
         _merge_bench_json(out)
     return out
@@ -201,9 +191,7 @@ INGEST_MODE_KEYS = ("per_query_ms", "sustained_qps",
                     "h2d_bytes_per_query", "marshal_ms_per_flush",
                     "dispatches_per_query")
 INGEST_TOP_KEYS = ("n_patients", "n_members", "reps", "input_len",
-                   "modes", "h2d_reduction_x",
-                   "h2d_reduction_device_x", "speedup_vs_legacy",
-                   "bitwise_device_vs_packed")
+                   "modes", "bitwise_device_vs_packed")
 
 
 def check_ingest_schema(out: Dict) -> None:
@@ -212,7 +200,7 @@ def check_ingest_schema(out: Dict) -> None:
     rot as the bench evolves."""
     for k in INGEST_TOP_KEYS:
         assert k in out, f"ingest bench missing key {k!r}"
-    for mode in ("legacy_marshal", "packed_host", "device_resident"):
+    for mode in ("packed_host", "device_resident"):
         assert mode in out["modes"], f"ingest bench missing mode {mode}"
         for k in INGEST_MODE_KEYS:
             assert k in out["modes"][mode], \
@@ -223,13 +211,9 @@ def check_ingest_schema(out: Dict) -> None:
 def bench_ingest(n_patients: int = 64, reps: int = 5,
                  input_len: int = 750, verbose=True,
                  write_json: bool = True) -> Dict:
-    """Ingest-side microbench of the flush marshaling regimes on the
+    """Ingest-side microbench of the flush's two input forms on the
     reduced zoo x ``n_patients`` streaming patients:
 
-    * ``legacy_marshal``   — the pre-refactor hot path: a host
-                             (member, patient) double loop builds one
-                             [M, Ppad, L, 1] input per bucket, M x L
-                             floats of H2D per patient;
     * ``packed_host``      — one [Ppad, 3, L] window pack per flush,
                              shipped once per device, lead-expanded to
                              the stacked view ON device (3 x L floats
@@ -239,9 +223,8 @@ def bench_ingest(n_patients: int = 64, reps: int = 5,
                              and only (patient, end, valid) int32
                              triples cross the host boundary.
 
-    Scores are asserted equivalent across modes (bitwise for
-    device-vs-packed).  Merged into ``BENCH_serving.json`` under
-    ``"ingest"``.
+    Scores are compared bitwise across the two modes.  Merged into
+    ``BENCH_serving.json`` under ``"ingest"``.
     """
     import jax
     from repro.configs.ecg_zoo import ECG_LEADS, zoo_specs
@@ -272,9 +255,7 @@ def bench_ingest(n_patients: int = 64, reps: int = 5,
             off += 250
         refs.append(di.close_window(p, 1.0))
 
-    feeds = {"legacy_marshal":
-             (EnsembleService(members, marshal="legacy"), windows),
-             "packed_host": (EnsembleService(members), windows),
+    feeds = {"packed_host": (EnsembleService(members), windows),
              "device_resident": (EnsembleService(members), refs)}
     out: Dict = {"n_patients": n_patients, "n_members": len(members),
                  "reps": reps, "input_len": input_len, "modes": {}}
@@ -296,26 +277,11 @@ def bench_ingest(n_patients: int = 64, reps: int = 5,
                 (svc.marshal_seconds - m0) / reps * 1e3,
             "dispatches_per_query": (svc.dispatch_count - d0) / n_q,
         }
-    # the modes must agree on the answers, not just the speed: packed
-    # vs legacy to float tolerance (different XLA programs), device vs
-    # packed BITWISE (same program, device-gathered inputs)
-    np.testing.assert_allclose(scores["packed_host"],
-                               scores["legacy_marshal"], atol=1e-6)
+    # the modes must agree on the answers, not just the speed: BITWISE
+    # (same program, device-gathered inputs)
     out["bitwise_device_vs_packed"] = bool(np.array_equal(
         np.asarray(scores["device_resident"]),
         np.asarray(scores["packed_host"])))
-    leg = out["modes"]["legacy_marshal"]
-    out["h2d_reduction_x"] = (leg["h2d_bytes_per_query"]
-                              / out["modes"]["packed_host"]
-                              ["h2d_bytes_per_query"])
-    out["h2d_reduction_device_x"] = (leg["h2d_bytes_per_query"]
-                                     / max(out["modes"]
-                                           ["device_resident"]
-                                           ["h2d_bytes_per_query"],
-                                           1e-9))
-    out["speedup_vs_legacy"] = {
-        m: leg["per_query_ms"] / out["modes"][m]["per_query_ms"]
-        for m in ("packed_host", "device_resident")}
     if verbose:
         print(f"\ningest bench (reduced zoo x {n_patients} patients, "
               f"L={input_len}):")
@@ -323,9 +289,7 @@ def bench_ingest(n_patients: int = 64, reps: int = 5,
             print(f"  {name:16s}: {m['per_query_ms']:7.2f} ms/query  "
                   f"{m['h2d_bytes_per_query']:9.0f} H2D B/query  "
                   f"marshal {m['marshal_ms_per_flush']:6.2f} ms/flush")
-        print(f"  H2D reduction: {out['h2d_reduction_x']:.1f}x packed, "
-              f"{out['h2d_reduction_device_x']:.0f}x device-resident; "
-              f"device bitwise == packed: "
+        print(f"  device bitwise == packed: "
               f"{out['bitwise_device_vs_packed']}")
     if write_json:
         _merge_bench_json({"ingest": out})

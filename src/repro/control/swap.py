@@ -9,8 +9,8 @@ queue and batcher are never touched, so no query is ever dropped by a
 swap.
 
 ``HotSwapper`` owns the expensive part off the hot path: building the
-new selector's stacked bucket params and compiling/warming its fused
-dispatch functions (``EnsembleService`` staging), so the swap itself is
+new selector's stacked bucket params and compiling/warming its bucket
+programs (``EnsembleService`` staging), so the swap itself is
 a pointer flip.  It extends ``SelectorLadder`` — an ordered
 cheapest-to-richest family of selectors the controller walks: ``shed``
 steps down to a cheaper ensemble under overload, ``climb`` steps back
@@ -228,7 +228,7 @@ class HotSwapper(SelectorLadder):
     def __init__(self, pool: Sequence, initial_selector: np.ndarray,
                  vitals_model=None, labs_model=None,
                  warmup_batch_sizes: Sequence[int] = (1, 2, 4, 8),
-                 fused: bool = True, impl: str = "xla",
+                 impl: str = "xla",
                  n_devices: int = 1,
                  devices: Optional[Sequence] = None,
                  placement_fn: Optional[
@@ -255,7 +255,6 @@ class HotSwapper(SelectorLadder):
         self.vitals_model = vitals_model
         self.labs_model = labs_model
         self.warmup_batch_sizes = tuple(warmup_batch_sizes)
-        self.fused = fused
         self.impl = impl
         # placement actuation: n_devices > 1 shards staged services via
         # LPT over measured bucket costs; placement_fn overrides the
@@ -348,7 +347,7 @@ class HotSwapper(SelectorLadder):
             svc = self._measure_cache.get(key)
         if svc is None:
             svc = EnsembleService.for_selector(
-                self.pool, selector, fused=True, impl=self.impl)
+                self.pool, selector, impl=self.impl)
             with self._stage_lock:
                 svc = self._measure_cache.setdefault(key, svc)
         return svc
@@ -362,7 +361,7 @@ class HotSwapper(SelectorLadder):
               placement: Optional[Placement] = None):
         """Build + warm the (selector, placement) service: stacked
         bucket params (``device_put``-sharded when placed), compiled
-        fused dispatch at the pow2 flush sizes.  ``placement=None``
+        bucket programs at the pow2 flush sizes.  ``placement=None``
         derives the selector's plan (or stays unsharded).  Idempotent:
         cached per pair; concurrent staging of the same pair waits on
         the build lock instead of duplicating the expensive
@@ -383,9 +382,8 @@ class HotSwapper(SelectorLadder):
                 return self._arm(svc)
             svc = EnsembleService.for_selector(
                 self.pool, sel, vitals_model=self.vitals_model,
-                labs_model=self.labs_model, fused=self.fused,
-                impl=self.impl, placement=placement,
-                devices=self.devices)
+                labs_model=self.labs_model, impl=self.impl,
+                placement=placement, devices=self.devices)
             if len(svc.members):
                 svc.warmup(batch_sizes=self.warmup_batch_sizes)
             with self._stage_lock:

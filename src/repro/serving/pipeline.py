@@ -6,47 +6,43 @@ members plus the CPU-side vitals/labs models; ``StreamingPipeline`` drives
 it from per-patient multi-modal streams and records end-to-end wall-clock
 latencies (the measured counterpart of the DES simulator).
 
-Fused serving (the hot path)
-----------------------------
-By default the service executes the zoo in **architecture buckets**
+The bucket path
+---------------
+The service executes the zoo in **architecture buckets**
 (``configs.ecg_zoo.bucket_zoo``): members with identical shapes — leads
 differ only in which input slice they consume — are stacked along a
 leading member axis (``launch.ensemble_parallel.stack_members``) and run
 as ONE ``ecg_apply_stacked`` dispatch per bucket, so a query costs
 ``n_buckets`` jitted calls (4 on the reduced 12-member zoo, 20 on the
-full 60) instead of ``n_members``.  ``predict_batch`` additionally
-micro-batches windows from MANY patients into the same stacked call.
-The per-member loop is kept (``fused=False``) as the equivalence oracle
-and for per-member cost measurement (``measured_costs``).
+full 60) instead of ``n_members``.  ``predict_batch`` micro-batches
+windows from MANY patients into the same stacked call.
 
-The one-transfer-per-device flush contract
-------------------------------------------
 A flush ships each patient's raw ``[ECG_LEADS, L]`` window to a device
 AT MOST ONCE — never once per stacked member.  The host builds one
-``[Ppad, ECG_LEADS, L]`` window pack per distinct input length (a
-single O(P) pass; left-zero-padding of short windows and pow2 batch
-padding land here), transfers it once per device that hosts a bucket
-shard, and every bucket's jitted dispatch does its own **lead-gather**
-on device: the bucket's static lead indices select member rows out of
-the shared pack inside the same XLA program as the stacked forward
-pass, so the old O(M x P) per-(member, patient) host marshaling loop —
-and its M-times-redundant H2D traffic (M x L floats per patient
-instead of ECG_LEADS x L) — is gone.  With **device-resident ingest**
-(``serving.aggregator.DeviceIngest``), a batch of
-``DeviceWindowRef``s skips even that single transfer: the pack is
-gathered straight out of the on-device ring buffers
+``[Ppad, ECG_LEADS, L]`` window pack per distinct input length
+(left-zero-padding of short windows and pow2 batch padding land here),
+transfers it once per device that hosts a bucket shard, and every
+bucket's jitted dispatch does its own **lead-gather** on device: the
+bucket's static lead indices select member rows out of the shared pack
+inside the same XLA program as the stacked forward pass.  With
+**device-resident ingest** (``serving.aggregator.DeviceIngest``), a
+batch of ``DeviceWindowRef``s skips even that single transfer: the pack
+is gathered straight out of the on-device ring buffers
 (``gather_windows``), and only the flushed (patient, end, valid) int32
-triples cross the host boundary.  The pre-refactor marshaling loop is
-preserved as ``marshal="legacy"`` — the ingest microbench's baseline
-and a second equivalence oracle.  ``h2d_bytes`` / ``marshal_seconds``
-counters account both regimes for ``BENCH_serving.json["ingest"]``.
+triples cross the host boundary.  ``h2d_bytes`` / ``marshal_seconds``
+count both forms.
+
+Eq. 5, the bagging combine, lives in one place: ``_side_scores`` (the
+CPU-side vitals and labs scores of one item) and ``_bag`` (the mean of
+the member column and those scores).  The flush's ``_combine`` and the
+slot engine's per-slot combine both call them.
 
 Member kinds
 ------------
 The service takes members of more than one kind.  Each kind supplies the
 member protocol: ``bucket_key()`` (members with equal keys share one
 stacked program), ``stack(params)`` (the bucket's weights as its program
-takes them), ``bucket_program(group, impl, marshal)`` (a cached jitted
+takes them), ``bucket_program(group, impl)`` (a cached jitted
 ``(stacked, win [Ppad, 3, L]) -> scores [M, Ppad]`` over the shared window
 pack), ``input_len``, ``cost()`` (MACs per window, for LPT) and
 ``tokens`` (sequence positions per window; 0 for a member that is not a
@@ -117,10 +113,10 @@ class ZooMember:
     def stack(params: Sequence[Dict]) -> Dict:
         return stack_members(list(params))
 
-    def bucket_program(self, group: Sequence["ZooMember"], impl: str,
-                       marshal: str) -> Callable:
+    def bucket_program(self, group: Sequence["ZooMember"],
+                       impl: str) -> Callable:
         return _make_bucket_fn(self.spec, [m.spec.lead for m in group],
-                               impl, marshal)
+                               impl)
 
 
 @dataclasses.dataclass
@@ -156,11 +152,8 @@ class HybridMember:
         # held as they are: stacking gigabytes of weights would copy them
         return tuple(params)
 
-    def bucket_program(self, group: Sequence["HybridMember"], impl: str,
-                       marshal: str) -> Callable:
-        if marshal != "packed":
-            raise ValueError(f"a {self.kind} member needs the packed "
-                             f"marshal, not {marshal!r}")
+    def bucket_program(self, group: Sequence["HybridMember"],
+                       impl: str) -> Callable:
         return _make_hybrid_fn(self.spec, self._ssd())
 
 
@@ -171,13 +164,6 @@ def bucket_members(members: Sequence) -> Dict[Hashable, List[int]]:
     for i, m in enumerate(members):
         out.setdefault(m.bucket_key(), []).append(i)
     return out
-
-
-def _resnext_only(members: Sequence, what: str) -> None:
-    kinds = sorted({m.kind for m in members} - {"resnext"})
-    if kinds:
-        raise ValueError(f"{what} runs ResNeXt programs only; {kinds} "
-                         f"members serve on the fused packed path")
 
 
 @dataclasses.dataclass
@@ -217,36 +203,17 @@ def _make_bucket_fn_cached(spec: EcgModelSpec, leads: Tuple[int, ...],
     return fn
 
 
-@functools.lru_cache(maxsize=None)
-def _make_bucket_fn_legacy_cached(spec: EcgModelSpec,
-                                  impl: str) -> Callable:
-    """Pre-refactor dispatch: takes the host-marshaled [M, Ppad, L, 1]
-    member-expanded input (``marshal="legacy"``) — kept as the ingest
-    microbench baseline and equivalence oracle."""
-    if not isinstance(spec, EcgModelSpec):
-        raise ValueError(f"the legacy marshal runs ResNeXt programs only, "
-                         f"not {type(spec).__name__}")
-
-    @jax.jit
-    def fn(stacked: Dict, xs: jax.Array) -> jax.Array:
-        logits = ecg_apply_stacked(stacked, xs, spec, impl=impl)
-        return jax.nn.softmax(logits, axis=-1)[..., 1]     # [M, P]
-    return fn
-
-
 def _make_bucket_fn(spec: EcgModelSpec, leads: Sequence[int],
-                    impl: str, marshal: str = "packed") -> Callable:
+                    impl: str) -> Callable:
     """Shared per (architecture, leads, impl): every service (and every
     staged (selector, placement) pair) reuses ONE jit object per bucket
     shape, so re-staging across swaps/placements hits the compile cache
     instead of recompiling identical programs.  ``name``/``lead`` are
-    blanked from the cache key; the packed form instead carries the
+    blanked from the cache key; the program instead carries the
     bucket's full lead TUPLE statically — the on-device gather is baked
     into the program, and two buckets whose representative members
     differ only by name share it."""
     blank = dataclasses.replace(spec, name="", lead=0)
-    if marshal == "legacy":
-        return _make_bucket_fn_legacy_cached(blank, impl)
     return _make_bucket_fn_cached(blank, tuple(leads), impl)
 
 
@@ -263,8 +230,8 @@ def _make_hybrid_fn(spec: GraniteHybridSpec, ssd_impl: str) -> Callable:
 
 
 # flush-size ladder: micro-batches pad up to aggregator.pow2_rung so
-# every path (packed / refs / legacy) and the ingest side share one
-# log2-bounded set of compiled shapes
+# host-dict and ref flushes and the ingest side share one log2-bounded
+# set of compiled shapes
 _next_pow2 = pow2_rung
 
 # representative flush rung for placement-planning cost measurement:
@@ -290,17 +257,14 @@ def _warmup_pack(L: int, p: int, channels: int = ECG_LEADS
 
 
 class EnsembleService:
-    """Stateless ensemble actors with a bucketed fused dispatch plan.
-
-    ``fused=True`` (default): one stacked jitted call per architecture
-    bucket per flush, micro-batched across patients.  ``fused=False``:
-    the original one-call-per-member-per-patient loop (kept as the
-    numerical oracle).  ``dispatch_count`` tallies jitted zoo dispatches
-    issued by ``predict``/``predict_batch`` — the quantity the serving
-    benchmark tracks per query.
+    """Stateless ensemble actors with a bucketed dispatch plan: one
+    stacked jitted call per architecture bucket per flush, micro-batched
+    across patients (see the module doc).  ``dispatch_count`` tallies
+    the stacked dispatches issued by ``predict``/``predict_batch`` — the
+    quantity the serving benchmark tracks per query.
 
     ``placement`` (a ``serving.placement.Placement`` whose assignment
-    covers every member exactly once) shards the fused plan across
+    covers every member exactly once) shards the bucket plan across
     ``devices`` (default ``jax.devices()``): slot d's members are
     bucketed on their own and pinned to device d, one stacked dispatch
     per (bucket, device) shard.  BUCKET-ALIGNED plans (each bucket
@@ -313,25 +277,17 @@ class EnsembleService:
 
     def __init__(self, members: Sequence[ZooMember],
                  vitals_model=None, labs_model=None,
-                 n_devices: int = 1, fused: bool = True,
-                 impl: str = "xla",
+                 n_devices: int = 1, impl: str = "xla",
                  placement: Optional[Placement] = None,
-                 devices: Optional[Sequence] = None,
-                 marshal: str = "packed"):
+                 devices: Optional[Sequence] = None):
         self.members = list(members)
         self.vitals_model = vitals_model
         self.labs_model = labs_model
-        self.fused = fused
         self.impl = impl
         self.n_devices = n_devices
         self.placement = placement
-        if marshal not in ("packed", "legacy"):
-            raise ValueError(f"unknown marshal mode {marshal!r}")
-        self.marshal = marshal
         self._devices = list(devices) if devices is not None else None
         if placement is not None:
-            if not fused:
-                raise ValueError("placement requires the fused path")
             placed = sorted(i for slot in placement.assignment
                             for i in slot)
             if placed != list(range(len(self.members))):
@@ -346,22 +302,20 @@ class EnsembleService:
         self.dispatch_guard: Optional[Callable] = None
         # ingest-side accounting for BENCH_serving.json["ingest"]:
         # bytes shipped host->device for flush inputs, and host seconds
-        # spent building/transferring them (the marshaling cost)
+        # spent building/transferring or gathering them
         self.h2d_bytes = 0
         self.marshal_seconds = 0.0
         # live per-shard retire times: (bucket member tuple) -> EWMA of
         # wall-clock seconds from that shard's dispatch to its retire
-        # on the fused flush path.  O(1) state per shard (no lists) —
+        # on the flush path.  O(1) state per shard (no lists) —
         # the drift signal HotSwapper.re_place / the controller's
         # finish-time imbalance consume.
         self.retire_alpha = RETIRE_ALPHA
         self._shard_ewma: Dict[Tuple[int, ...], float] = {}
         self._count_lock = threading.Lock()    # server workers share us
-        if not fused:
-            _resnext_only(self.members, "fused=False")
-        self._fns: List[Optional[Callable]] = [
-            _make_member_fn(m.params, m.spec, impl)
-            if m.kind == "resnext" else None for m in self.members]
+        # per-member jit objects for measured_costs, built on its first
+        # call: serving never runs a member alone
+        self._member_fns: Optional[List[Callable]] = None
         self._bucket_cache: Optional[List[_Bucket]] = None
 
     @classmethod
@@ -375,7 +329,7 @@ class EnsembleService:
     # ------------------------------------------------------------ plan
     @property
     def _buckets(self) -> List[_Bucket]:
-        """Stacked dispatch plan, built lazily on first fused flush (so
+        """Stacked dispatch plan, built lazily on first flush (so
         measurement-only services never pay the param stacking)."""
         if self._bucket_cache is None:
             with self._count_lock:
@@ -416,7 +370,7 @@ class EnsembleService:
                     spec=rep.spec, idx=idx,
                     leads=[getattr(m.spec, "lead", None) for m in group],
                     stacked=stacked,
-                    fn=rep.bucket_program(group, self.impl, self.marshal),
+                    fn=rep.bucket_program(group, self.impl),
                     device=dev, slot=slot_idx, kind=rep.kind,
                     tokens=rep.tokens))
         return out
@@ -462,11 +416,7 @@ class EnsembleService:
 
     # ---------------------------------------------------------- warmup
     def _bucket_input(self, b: _Bucket, p: int) -> jax.Array:
-        if self.marshal == "legacy":
-            x = np.zeros((len(b.idx), p, b.spec.input_len, 1),
-                         np.float32)
-        else:
-            x = _warmup_pack(b.spec.input_len, p)
+        x = _warmup_pack(b.spec.input_len, p)
         if b.device is not None:
             return jax.device_put(x, b.device)
         return jnp.asarray(x)
@@ -475,32 +425,34 @@ class EnsembleService:
         """Compile every bucket dispatch at the pow2 flush-size ladder
         (the sizes ``predict_batch`` pads to), so the first full-census
         flush after build/staging never pays XLA compile on the
-        latency-critical path.  Packed mode shares one zero window pack
-        per (input length, flush size, device) across all buckets."""
-        if self.fused:
-            shared: Dict = {}
-            for b in self._buckets:
-                for p in batch_sizes:
-                    key = (b.spec.input_len, b.device, p)
-                    x = shared.get(key)
-                    if x is None or self.marshal == "legacy":
-                        x = self._bucket_input(b, p)
-                        shared[key] = x
-                    b.fn(b.stacked, x).block_until_ready()
-        else:
-            for m, fn in zip(self.members, self._fns):
-                fn(jnp.zeros((1, m.spec.input_len, 1)))
+        latency-critical path.  One zero window pack per (input length,
+        flush size, device) is shared across all buckets."""
+        shared: Dict = {}
+        for b in self._buckets:
+            for p in batch_sizes:
+                key = (b.spec.input_len, b.device, p)
+                x = shared.get(key)
+                if x is None:
+                    x = shared[key] = self._bucket_input(b, p)
+                b.fn(b.stacked, x).block_until_ready()
 
     def measured_costs(self, reps: int = 3,
                        warmup: int = 1) -> List[float]:
-        """Closed-loop per-member seconds/query (the mu measurement).
-        Always uses the per-member fns — the composer's latency profiler
-        needs individual member costs regardless of fused serving.
+        """Closed-loop per-member seconds/query (the mu measurement):
+        one jitted call per member, since the composer's latency
+        profiler needs individual member costs, not bucket costs.
         ``warmup`` untimed calls precede the timed reps so compile time
         never leaks into the estimate."""
-        _resnext_only(self.members, "measured_costs")
+        kinds = sorted({m.kind for m in self.members} - {"resnext"})
+        if kinds:
+            raise ValueError(f"measured_costs times ResNeXt members "
+                             f"only, not {kinds}")
+        if self._member_fns is None:
+            self._member_fns = [_make_member_fn(m.params, m.spec,
+                                                self.impl)
+                                for m in self.members]
         out = []
-        for m, fn in zip(self.members, self._fns):
+        for m, fn in zip(self.members, self._member_fns):
             x = jnp.zeros((1, m.spec.input_len, 1))
             for _ in range(max(1, warmup)):
                 fn(x).block_until_ready()
@@ -537,21 +489,17 @@ class EnsembleService:
     def predict_batch(self, batch) -> List[float]:
         """Micro-batched form of ``predict``: one flush for windows
         from len(batch) patients — host window dicts or
-        ``DeviceWindowRef``s (never mixed).  Fused packed path: ONE
-        [Ppad, 3, L] window pack per distinct input length, shipped at
-        most once per device, lead-expanded to the stacked bucket view
-        inside each bucket's dispatch; all device work is retired with
-        a single blocking gather at the end.  ECG windows shorter than
+        ``DeviceWindowRef``s (never mixed).  ONE [Ppad, 3, L] window
+        pack per distinct input length, shipped at most once per
+        device, lead-expanded to the stacked bucket view inside each
+        bucket's dispatch; all device work is retired with a single
+        blocking gather at the end.  ECG windows shorter than
         a member's input_len are left-zero-padded (the aggregator's
         zero-fill convention), keeping compile shapes static."""
         if not len(batch):
             return []
         if isinstance(batch[0], DeviceWindowRef):
             return self._predict_refs(batch)
-        if not self.fused:
-            return [self._predict_one_unfused(w) for w in batch]
-        if self.marshal == "legacy":
-            return self._predict_batch_legacy(batch)
 
         P = len(batch)
         # pad the micro-batch to the next power of two: per-window
@@ -640,7 +588,7 @@ class EnsembleService:
     def shard_cost_snapshot(self) -> Dict[Tuple[int, ...], float]:
         """Live per-shard retire EWMAs, keyed by the shard's sorted
         member-index tuple (stable across re-placements for
-        bucket-aligned plans).  Empty until the first fused flush."""
+        bucket-aligned plans).  Empty until the first flush."""
         with self._count_lock:
             return dict(self._shard_ewma)
 
@@ -703,13 +651,6 @@ class EnsembleService:
         Sharded plans copy the gathered pack device-to-device once per
         shard device.  Bitwise-identical to the host-dict path fed the
         same windows."""
-        if not self.fused:
-            return [self._predict_one_unfused(self._ref_windows(r))
-                    for r in batch]
-        if self.marshal == "legacy":
-            raise ValueError("DeviceWindowRef flushes need the packed "
-                             "marshal (legacy expects member-expanded "
-                             "host inputs)")
         ingest = batch[0].ingest
         if any(r.ingest is not ingest for r in batch):
             raise ValueError("a flush must come from one DeviceIngest")
@@ -796,81 +737,12 @@ class EnsembleService:
         return [{**r.extra, "vitals": win[p]}
                 for p, r in enumerate(batch)]
 
-    def _ref_windows(self, r: DeviceWindowRef) -> Dict[str, np.ndarray]:
-        """Materialize a ref as the oracle's host window dict (unfused
-        fallback only — the fused path never reads samples back)."""
-        out = dict(r.extra)
-        for name in r.ends:
-            out[name] = r.host_window(name)
-        return out
-
-    def _predict_batch_legacy(self, batch) -> List[float]:
-        """Pre-refactor hot path: per bucket an [M, Ppad, L, 1] input
-        is marshaled by a host (member, patient) double loop and
-        shipped whole — M x L floats per patient per bucket.  Kept
-        behind ``marshal="legacy"`` as the ingest bench baseline."""
-        _resnext_only(self.members, 'marshal="legacy"')
-        P = len(batch)
-        Ppad = _next_pow2(P)
-        score_mat = np.zeros((len(self.members), P))
-        pending = []
-        h2d = 0
-        t_marshal = time.perf_counter()
-        guard = self.dispatch_guard
-        for b in self._buckets:
-            if guard is not None:
-                guard(b.device)
-            L = b.spec.input_len
-            xs = np.zeros((len(b.idx), Ppad, L, 1), np.float32)
-            for j, lead in enumerate(b.leads):
-                for p, w in enumerate(batch):
-                    clip = np.asarray(w["ecg"])[lead, -L:]
-                    xs[j, p, L - clip.shape[-1]:, 0] = clip
-            h2d += xs.nbytes
-            # sharded plan: pin the input beside its pinned params so
-            # the dispatch runs on (and stays on) the shard's device
-            x = jax.device_put(xs, b.device) if b.device is not None \
-                else jnp.asarray(xs)
-            y = b.fn(b.stacked, x)
-            pending.append((b, y))                     # async dispatch
-        marshal_s = time.perf_counter() - t_marshal
-        # legacy interleaves marshal + dispatch per bucket; attribute
-        # the whole pre-gather segment to marshal
-        _spans.note("marshal", marshal_s)
-        with self._count_lock:
-            self.dispatch_count += len(pending)
-            self.h2d_bytes += h2d
-            self.marshal_seconds += marshal_s
-        t_gather = time.perf_counter()
-        for b, y in pending:      # one sync point: cross-device gather
-            score_mat[b.idx] = np.asarray(
-                jax.block_until_ready(y))[:, :P]
-        _spans.note("gather", time.perf_counter() - t_gather)
-        return self._combine(score_mat, batch)
-
-    def _predict_one_unfused(self, windows: Dict[str, np.ndarray]
-                             ) -> float:
-        _resnext_only(self.members, "the unfused per-member path")
-        ecg = windows.get("ecg")
-        if self.dispatch_guard is not None:
-            self.dispatch_guard(None)       # unfused runs on the default
-        score_mat = np.zeros((len(self.members), 1))
-        for i, (m, fn) in enumerate(zip(self.members, self._fns)):
-            L = m.spec.input_len
-            clip = np.asarray(ecg)[m.spec.lead, -L:]
-            if clip.shape[-1] < L:     # zero-fill short windows (matches
-                clip = np.pad(clip, (L - clip.shape[-1], 0))  # aggregator)
-            score_mat[i, 0] = float(fn(jnp.asarray(clip)[None, :, None])[0])
-        with self._count_lock:
-            self.dispatch_count += len(self.members)
-        return self._combine(score_mat, [windows])[0]
-
     def _side_input(self, item, name: str) -> Optional[np.ndarray]:
         """The CPU-side models' input for one batch item: a window-dict
         key, or — for a ``DeviceWindowRef`` — the labs side channel /
         a lazy readback of the (tiny, low-rate) vitals window.  Only
-        read when the matching model is attached, so the fused ECG
-        path stays readback-free."""
+        read when the matching model is attached, so the ECG path
+        stays readback-free."""
         if isinstance(item, DeviceWindowRef):
             if name in item.extra:
                 return item.extra[name]
@@ -879,23 +751,31 @@ class EnsembleService:
             return None
         return item.get(name)
 
-    def _combine(self, score_mat: np.ndarray, batch) -> List[float]:
-        """Per-patient Eq. 5 mean over zoo scores + CPU-side models."""
-        out = []
-        for p, windows in enumerate(batch):
-            scores = list(score_mat[:, p]) if len(self.members) else []
-            if self.vitals_model is not None:
-                vit = self._side_input(windows, "vitals")
-                if vit is not None:
-                    scores.append(float(self.vitals_model.predict_proba(
-                        vit[None])[0]))
-            if self.labs_model is not None:
-                labs = self._side_input(windows, "labs")
-                if labs is not None:
-                    scores.append(float(self.labs_model.predict_proba(
-                        labs[None])[0]))
-            out.append(float(np.mean(scores)) if scores else 0.5)
+    def _side_scores(self, item) -> List[float]:
+        """Eq. 5's CPU-side terms for one batch item, in its order: the
+        vitals model's score, then the labs model's, each only when the
+        model is attached and the item has its input."""
+        out: List[float] = []
+        for name, model in (("vitals", self.vitals_model),
+                            ("labs", self.labs_model)):
+            if model is not None:
+                x = self._side_input(item, name)
+                if x is not None:
+                    out.append(float(model.predict_proba(x[None])[0]))
         return out
+
+    @staticmethod
+    def _bag(score_col: np.ndarray, side: List[float]) -> float:
+        """Eq. 5 for one item: the float64 mean over the python list of
+        member scores with the side-model scores appended; 0.5 when
+        the list is empty."""
+        scores = list(score_col) + side
+        return float(np.mean(scores)) if scores else 0.5
+
+    def _combine(self, score_mat: np.ndarray, batch) -> List[float]:
+        """Per-patient Eq. 5 over a flush's ``[M, P]`` member scores."""
+        return [self._bag(score_mat[:, p], self._side_scores(item))
+                for p, item in enumerate(batch)]
 
 
 class TierRouter:
@@ -968,7 +848,7 @@ class StreamingPipeline:
     service.
 
     ``engine="slots"`` (requires ``device_ingest=True``, untiered, a
-    plain fused ``EnsembleService``) switches from flush-per-window to
+    plain ``EnsembleService``) switches from flush-per-window to
     the continuous slot engine (``serving.slots.SlotEngine``): a
     closed window UPDATES the bed's slot, and every ``tick_seconds``
     of logical stream time one tick rescores all occupied slots —

@@ -22,8 +22,7 @@ always-fresh per-patient STATE that queries merely read.
   as the flush path (``pipeline._make_bucket_fn`` jit objects, so the
   tick shares the flush's compile cache), and ONE **donated** jitted
   update step per device group that applies the occupancy mask to the
-  member-score state in place and writes the ``[n_slots]`` combined
-  score vector that stays on device (``device_scores``);
+  member-score state in place;
 * a query becomes "read slot k's latest score" — host int indexing
   into the engine's mirror, **zero H2D and zero dispatches per
   query**.  The tick's ``n_buckets + 1`` dispatches amortize over
@@ -35,16 +34,13 @@ Bitwise oracle contract
 Because the tick reuses the flush's OWN bucket jit objects and the
 masked update merely *selects* freshly computed columns, a slot's
 score is bitwise-identical to ``predict_batch`` over the same refs
-(the flush path stays the oracle, exactly like ``marshal="legacy"``
-is the oracle for the packed marshal).  The host ``read()`` surface
-replicates ``EnsembleService._combine``'s float64 mean + CPU-side
-vitals/labs models verbatim from a per-tick readback of the member
-score matrix, so even the combined score matches the oracle bit for
-bit.  (The on-device ``device_scores`` vector is the float32 ECG-zoo
-mean — the mesh-facing artifact — and is NOT the oracle surface.)
-One caveat inherited from XLA: a flush of exactly ONE window compiles
-a different (batch-1-specialized) program, so the oracle comparison
-holds for flushes of two or more windows.
+(the flush path stays the oracle).  The host ``read()`` surface is
+Eq. 5 through the service's own ``_side_scores`` / ``_bag`` — the
+calls the flush's ``_combine`` makes — over a per-tick readback of the
+member score matrix, so even the combined score matches the oracle
+bit for bit.  One caveat inherited from XLA: a flush of exactly ONE
+window compiles a different (batch-1-specialized) program, so the
+oracle comparison holds for flushes of two or more windows.
 
 Side-model scores are a property of the close, not of the tick.  The
 vitals window a slot's combine reads ends where its close put it and
@@ -124,19 +120,12 @@ def _masked_update(prev: jax.Array, cands: Tuple[jax.Array, ...],
     """The donated slot-state step: merge this tick's freshly scored
     member columns (``cands``, one ``[m_i, S]`` block per bucket in
     group order) into the persistent ``[M_g, S]`` member-score state
-    behind the occupancy mask, in place (``prev`` is donated), and
-    emit the group's ``[S]`` combined score vector.  ``where`` only
-    SELECTS columns, so a scored slot's state is bitwise the bucket
-    dispatch's output."""
+    behind the occupancy mask, in place (``prev`` is donated).  ``where``
+    only SELECTS columns, so a scored slot's state is bitwise the bucket
+    dispatch's output.  The tick reads element 0; the group's ``[S]``
+    member mean, element 1, is read by nothing."""
     new = jnp.where(occ[None, :], jnp.concatenate(cands, axis=0), prev)
     return new, jnp.mean(new, axis=0)
-
-
-@jax.jit
-def _fleet_mean(mats: Tuple[jax.Array, ...]) -> jax.Array:
-    """Cross-group combine for sharded plans: the [S] member mean over
-    every device group's score matrix (brought to one device first)."""
-    return jnp.mean(jnp.concatenate(mats, axis=0), axis=0)
 
 
 class _Close(dict):
@@ -202,7 +191,7 @@ class TickReport:
 class SlotEngine:
     """Persistent patient-slot scoring over a ``DeviceIngest`` census.
 
-    ``service`` must be a fused, packed-marshal ``EnsembleService``
+    ``service`` must be an ``EnsembleService`` with at least one member
     (optionally placement-sharded); ``ingest`` the census's
     ``DeviceIngest`` (slot k == patient k — a bed owns its ring row).
 
@@ -220,11 +209,6 @@ class SlotEngine:
     """
 
     def __init__(self, service, ingest: DeviceIngest):
-        if not getattr(service, "fused", False):
-            raise ValueError("SlotEngine needs a fused EnsembleService")
-        if getattr(service, "marshal", "packed") != "packed":
-            raise ValueError("SlotEngine needs the packed marshal (the "
-                             "tick gathers windows on device)")
         if not service.members:
             raise ValueError("SlotEngine needs at least one zoo member")
         if "ecg" not in ingest.modalities:
@@ -237,8 +221,6 @@ class SlotEngine:
         self._lens = tuple(sorted({b.spec.input_len
                                    for b in service._buckets}))
         self.groups: List[_Group] = self._build_groups(service)
-        # [Spad] f32 combined (zoo-mean) score vector, stays on device
-        self.device_scores: Optional[jax.Array] = None
         self._pj = jnp.asarray(
             np.pad(np.arange(self.n_slots, dtype=np.int32),
                    (0, self._Spad - self.n_slots)))
@@ -325,10 +307,6 @@ class SlotEngine:
         the gap (stale, never wrong)."""
         if service is self.service:
             return
-        if not getattr(service, "fused", False) or \
-                getattr(service, "marshal", "packed") != "packed":
-            raise ValueError("rebind needs a fused, packed "
-                             "EnsembleService")
         old = [getattr(m, "name", None) for m in self.service.members]
         new = [getattr(m, "name", None) for m in service.members]
         if old != new:
@@ -339,7 +317,6 @@ class SlotEngine:
             self._lens = tuple(sorted({b.spec.input_len
                                        for b in service._buckets}))
             self.groups = self._build_groups(service)
-            self.device_scores = None
             self._side_gen += 1
             with self._lock:
                 self.n_rebinds += 1
@@ -433,7 +410,6 @@ class SlotEngine:
                         g.state, ((0, 0), (0, new_spad - old_spad)))
                     g.state = (jax.device_put(grown, g.device)
                                if g.device is not None else grown)
-                self.device_scores = None
             return self.n_slots
 
     def _admit_locked(self, slot: int) -> None:
@@ -665,20 +641,13 @@ class SlotEngine:
         # ---- phase 2: fold.  Every guard has passed; the donated
         # updates commit each group's state for this tick.
         with _spans.phase("slots.tick.fold"):
-            combined = None
             for g, cands in zip(self.groups, group_cands):
-                g.state, combined = _masked_update(
-                    g.state, cands, occ_dev[g.device])
-            if len(self.groups) == 1:
-                self.device_scores = combined
-            else:
-                anchor = self.groups[0].device
-                self.device_scores = _fleet_mean(tuple(
-                    jax.device_put(g.state, anchor) for g in self.groups))
+                g.state = _masked_update(
+                    g.state, cands, occ_dev[g.device])[0]
 
-        # host mirror: exact _combine numerics (float64 mean over the
-        # member column + CPU-side vitals/labs models) from one small
-        # per-tick readback — this sync point plays the flush's gather
+        # host mirror: the flush's Eq. 5 (float64 mean over the member
+        # column + CPU-side vitals/labs models) from one small per-tick
+        # readback — this sync point plays the flush's gather
         with _spans.phase("slots.tick.readback"):
             score_mat = np.zeros((len(svc.members), spad))
             for g in self.groups:
@@ -731,43 +700,28 @@ class SlotEngine:
             hybrid_tokens=tokens, side_calls=side_calls,
             side_reused=side_reused)
 
-    def _host_combine(self, score_col: np.ndarray, extra: Dict,
+    def _host_combine(self, score_col: np.ndarray, extra: _Close,
                       vit_row: Optional[np.ndarray]) -> float:
-        """``EnsembleService._combine`` for one slot, verbatim: python
-        list of float64 member scores, CPU-side models appended in the
-        same order, ``np.mean`` over the list.  The CPU-side scores
-        come from ``_side_scores``: run once per close, then kept."""
-        svc = self.service
-        scores = list(score_col) if len(svc.members) else []
-        if svc.vitals_model is not None or svc.labs_model is not None:
-            scores += self._side_scores(extra, vit_row)
-        return float(np.mean(scores)) if scores else 0.5
-
-    def _side_scores(self, extra: _Close,
-                     vit_row: Optional[np.ndarray]) -> List[float]:
-        """The vitals and labs scores of the close ``extra`` records, in
-        ``_combine``'s order: the floats an earlier combine of this
-        close kept on its record when the side generation still
-        matches, else one ``predict_proba`` row per model, kept."""
-        memo = extra.side
-        if memo is not None and memo[0] == self._side_gen:
-            self.side_reused += 1
-            return memo[1]
+        """The flush's Eq. 5 for one slot (``EnsembleService._bag``).
+        The side-model scores of the close ``extra`` records
+        (``EnsembleService._side_scores``, with the tick's vitals row in
+        place of the close's own when there is one) run once per close
+        and are kept on its record while the side generation still
+        matches."""
         svc = self.service
         side: List[float] = []
-        if svc.vitals_model is not None:
-            vit = vit_row if vit_row is not None else extra.get("vitals")
-            if vit is not None:
-                side.append(float(
-                    svc.vitals_model.predict_proba(vit[None])[0]))
-        if svc.labs_model is not None:
-            labs = extra.get("labs")
-            if labs is not None:
-                side.append(float(
-                    svc.labs_model.predict_proba(labs[None])[0]))
-        extra.side = (self._side_gen, side)
-        self.side_calls += 1
-        return side
+        if svc.vitals_model is not None or svc.labs_model is not None:
+            memo = extra.side
+            if memo is not None and memo[0] == self._side_gen:
+                self.side_reused += 1
+                side = memo[1]
+            else:
+                side = svc._side_scores(
+                    extra if vit_row is None
+                    else {**extra, "vitals": vit_row})
+                extra.side = (self._side_gen, side)
+                self.side_calls += 1
+        return svc._bag(score_col, side)
 
     # ------------------------------------------------------------ reads
     def read(self, slot: int,

@@ -74,3 +74,79 @@ def zoo_members():
     specs = zoo_specs(reduced=True, input_len=250)
     return [ZooMember(s, init_ecg(jax.random.PRNGKey(i), s))
             for i, s in enumerate(specs)]
+
+
+def _eq5(member_scores, item, vitals_model, labs_model):
+    """Eq. 5 written out plainly: the mean over the member scores and
+    the attached side models' scores (vitals, then labs); 0.5 when
+    there is nothing to bag."""
+    scores = [float(s) for s in member_scores]
+    for name, model in (("vitals", vitals_model), ("labs", labs_model)):
+        if model is not None and item.get(name) is not None:
+            scores.append(float(model.predict_proba(item[name][None])[0]))
+    return float(np.mean(scores)) if scores else 0.5
+
+
+class MemberLoop:
+    """The plain per-member oracle: one ``ecg_apply`` call per member
+    on its own lead, left-zero-padded to its input length, softmax,
+    then Eq. 5 with the side models.  ``dispatch_count`` counts the
+    member calls."""
+
+    def __init__(self, members, vitals_model=None, labs_model=None):
+        import jax
+        from repro.models.ecg_resnext import ecg_apply
+        self.members = list(members)
+        self.vitals_model = vitals_model
+        self.labs_model = labs_model
+        self.dispatch_count = 0
+        self._fns = [jax.jit(lambda x, p=m.params, s=m.spec: jax.nn.softmax(
+            ecg_apply(p, x, s), axis=-1)[:, 1]) for m in self.members]
+
+    def predict(self, item) -> float:
+        col = []
+        for m, fn in zip(self.members, self._fns):
+            L = m.spec.input_len
+            clip = np.asarray(item["ecg"], np.float32)[m.spec.lead, -L:]
+            clip = np.pad(clip, (L - clip.shape[-1], 0))
+            col.append(float(fn(clip[None, :, None])[0]))
+            self.dispatch_count += 1
+        return _eq5(col, item, self.vitals_model, self.labs_model)
+
+
+def host_marshal_predict(service, batch):
+    """The host-marshal oracle: each of ``service``'s buckets runs the
+    stacked forward pass (``ecg_apply_stacked``, softmax) on an
+    ``[M, Ppad, L, 1]`` input built on the host by a (member, patient)
+    loop, then Eq. 5 per patient.  Same weights and stacking as the
+    service; only where the lead expansion happens differs."""
+    import jax
+    from repro.models.ecg_resnext import ecg_apply_stacked
+    from repro.serving.aggregator import pow2_rung
+    P = len(batch)
+    mat = np.zeros((len(service.members), P))
+    for b in service._buckets:
+        L = b.spec.input_len
+        xs = np.zeros((len(b.idx), pow2_rung(P), L, 1), np.float32)
+        for j, lead in enumerate(b.leads):
+            for p, item in enumerate(batch):
+                clip = np.asarray(item["ecg"], np.float32)[lead, -L:]
+                xs[j, p, L - clip.shape[-1]:, 0] = clip
+        y = jax.jit(lambda st, x, s=b.spec: jax.nn.softmax(
+            ecg_apply_stacked(st, x, s, impl=service.impl),
+            axis=-1)[..., 1])(b.stacked, xs)
+        mat[b.idx] = np.asarray(y)[:, :P]
+    return [_eq5(mat[:, p], item, service.vitals_model,
+                 service.labs_model) for p, item in enumerate(batch)]
+
+
+@pytest.fixture(scope="session")
+def member_loop():
+    """``MemberLoop``: the per-member oracle's constructor."""
+    return MemberLoop
+
+
+@pytest.fixture(scope="session")
+def host_marshal():
+    """``host_marshal_predict``: the host-marshal oracle."""
+    return host_marshal_predict

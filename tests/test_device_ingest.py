@@ -274,9 +274,6 @@ def test_stale_ref_refused_not_silently_wrong(zoo_members, rng):
               rng.standard_normal((3, 250)).astype(np.float32))
     with pytest.raises(ValueError, match="stale"):
         svc.predict_batch([ref])
-    # the unfused oracle path reads back via host_window: same guard
-    with pytest.raises(ValueError, match="stale"):
-        EnsembleService(zoo_members, fused=False).predict(ref)
 
 
 def test_stale_vitals_ring_refused(zoo_members, rng):
@@ -334,32 +331,29 @@ def test_refs_with_cpu_side_models(zoo_members, rng):
 
 
 def test_refs_reject_legacy_marshal_and_mixed_ingest(zoo_members, rng):
-    legacy = EnsembleService(zoo_members, marshal="legacy")
+    """A flush of refs from two different ``DeviceIngest``s is refused
+    (the marshal knob this test also covered is gone)."""
     windows = [{"ecg": rng.standard_normal((3, 250)).astype(np.float32)}
                for _ in range(2)]
     _, refs_a = _ingest_windows(windows[:1])
     _, refs_b = _ingest_windows(windows[1:])
-    with pytest.raises(ValueError):
-        legacy.predict_batch(refs_a)
     svc = EnsembleService(zoo_members)
-    with pytest.raises(ValueError):
+    with pytest.raises(ValueError, match="one DeviceIngest"):
         svc.predict_batch([refs_a[0], refs_b[0]])
-    with pytest.raises(ValueError):
-        EnsembleService(zoo_members, marshal="nope")
 
 
-def test_legacy_marshal_matches_packed(zoo_members, rng):
-    """The preserved pre-refactor marshaling loop is still a correct
-    oracle for the packed path."""
+def test_legacy_marshal_matches_packed(zoo_members, host_marshal, rng):
+    """The packed path (lead expansion inside each bucket program)
+    against the host-marshal oracle (the same stacked forward pass on
+    an [M, Ppad, L, 1] input expanded on the host)."""
     windows = [{"ecg": rng.standard_normal((3, 250)).astype(np.float32)}
                for _ in range(5)]
     packed = EnsembleService(zoo_members)
-    legacy = EnsembleService(zoo_members, marshal="legacy")
     np.testing.assert_allclose(packed.predict_batch(windows),
-                               legacy.predict_batch(windows),
+                               host_marshal(packed, windows),
                                atol=1e-6)
-    # the packed pack ships 3 leads once vs M member rows: M/3 less H2D
-    assert legacy.h2d_bytes == 4 * packed.h2d_bytes
+    # one [Ppad, 3, L] float32 pack per flush: 3 leads, not M rows
+    assert packed.h2d_bytes == 8 * 3 * 250 * 4
 
 
 # ------------------------------------------------- pipeline equivalence
@@ -567,7 +561,11 @@ def test_bench_ingest_smoke_schema():
     out = bench_ingest(n_patients=2, reps=1, input_len=250,
                        verbose=False, write_json=False)
     check_ingest_schema(out)
-    assert out["h2d_reduction_x"] == pytest.approx(4.0)
+    # host-dict flushes ship one [Ppad, 3, L] float32 pack; ref flushes
+    # ship no samples, only the (patient, end, valid) int32 triples
+    modes = out["modes"]
+    assert modes["packed_host"]["h2d_bytes_per_query"] == 3 * 250 * 4
+    assert modes["device_resident"]["h2d_bytes_per_query"] == 3 * 4
 
 
 # ------------------------------------------------------- staged ingest

@@ -1,6 +1,7 @@
 """Fused stacked-member ensemble serving + cross-patient micro-batching:
 
-* bucketed/stacked ``predict`` must match the per-member loop to 1e-5;
+* bucketed/stacked ``predict`` must match the per-member loop oracle
+  (``conftest.MemberLoop``) to 1e-5;
 * ``predict_batch`` must match per-patient ``predict``;
 * dispatch counts collapse from n_members to n_buckets;
 * ``MicroBatcher`` flush semantics (max_batch / max_wait bounds);
@@ -36,10 +37,8 @@ def test_full_zoo_buckets_20():
 
 # ----------------------------------------------------------- equivalence
 @pytest.fixture(scope="module")
-def services(zoo_members):
-    fused = EnsembleService(zoo_members, fused=True)
-    loop = EnsembleService(zoo_members, fused=False)
-    return fused, loop
+def services(zoo_members, member_loop):
+    return EnsembleService(zoo_members), member_loop(zoo_members)
 
 
 def _windows(rng, n=1, L=250):
@@ -73,7 +72,7 @@ def test_fused_dispatch_count_is_n_buckets(services, rng):
     assert loop.dispatch_count - d0 == len(loop.members) == 12
 
 
-def test_fused_with_cpu_side_models(zoo_members, rng):
+def test_fused_with_cpu_side_models(zoo_members, member_loop, rng):
     class Const:
         def __init__(self, v):
             self.v = v
@@ -83,8 +82,8 @@ def test_fused_with_cpu_side_models(zoo_members, rng):
 
     svc = EnsembleService(zoo_members, vitals_model=Const(0.9),
                           labs_model=Const(0.1))
-    ref = EnsembleService(zoo_members, vitals_model=Const(0.9),
-                          labs_model=Const(0.1), fused=False)
+    ref = member_loop(zoo_members, vitals_model=Const(0.9),
+                      labs_model=Const(0.1))
     w = _windows(rng)[0]
     w["vitals"] = rng.standard_normal((7, 3)).astype(np.float32)
     w["labs"] = rng.standard_normal(8).astype(np.float32)
@@ -94,13 +93,28 @@ def test_fused_with_cpu_side_models(zoo_members, rng):
                                                  abs=1e-5)
 
 
+def test_measured_costs_builds_member_programs_on_first_call(zoo_members):
+    """Serving never runs a member alone, so a service holds no
+    per-member program until ``measured_costs`` first asks for one;
+    later calls reuse them."""
+    svc = EnsembleService(zoo_members)
+    assert svc._member_fns is None
+    costs = svc.measured_costs(reps=1)
+    assert len(costs) == len(zoo_members) and min(costs) > 0
+    fns = svc._member_fns
+    assert len(fns) == len(zoo_members)
+    svc.measured_costs(reps=1)
+    assert svc._member_fns is fns
+
+
 def test_empty_batch():
     assert EnsembleService([]).predict_batch([]) == []
 
 
 def test_short_window_zero_padded_both_paths(services, rng):
     """ECG windows shorter than input_len are left-zero-filled (the
-    aggregator convention) on BOTH paths, and they still agree."""
+    aggregator convention) by the service and by the member-loop
+    oracle, and they still agree."""
     fused, loop = services
     w = {"ecg": rng.standard_normal((3, 100)).astype(np.float32)}
     got = fused.predict(w)

@@ -104,8 +104,6 @@ def test_tick_bitwise_vs_flush_oracle(zoo_members, rng):
     assert eng.dispatch_count == 2 * svc.n_buckets
     assert eng.tick_count == 2
     np.testing.assert_array_equal(eng.scores(), got)
-    # the on-device artifact exists, is slot-batch sized and on device
-    assert eng.device_scores.shape == (pow2_rung(8),)
 
 
 def test_tick_partial_occupancy(zoo_members, rng):
@@ -431,10 +429,6 @@ def test_stale_ring_skipped_and_tick_age_guard(zoo_members, rng):
 
 def test_engine_rejects_wrong_service_or_ingest(zoo_members, rng):
     di = _make_ingest(2)
-    with pytest.raises(ValueError, match="fused"):
-        SlotEngine(EnsembleService(zoo_members, fused=False), di)
-    with pytest.raises(ValueError, match="packed"):
-        SlotEngine(EnsembleService(zoo_members, marshal="legacy"), di)
     with pytest.raises(ValueError, match="ecg"):
         SlotEngine(EnsembleService(zoo_members),
                    DeviceIngest([ModalitySpec("vitals", 1.0, 7)],
